@@ -44,19 +44,13 @@ on them:
                                throughput on hostile streams
   serving_mesh_shards        — dp=4 engine on the shard_map allocation
                                plane (DESIGN.md §9).  Needs >= 4
-                               devices; a single-device process
-                               re-execs itself under
-                               ``--xla_force_host_platform_device_count=8``
-                               (``--emit-json mesh_shards``) so the
-                               numbers always measure the real mesh —
-                               never the silent vmap fallback — or
+                               devices in this process; with fewer it
                                records an explicit ``skipped_no_mesh``
-                               marker if even the re-exec fails
+                               marker, never the vmap fallback's numbers
 
 CLI modes (besides the default full run):
   --emit-json NAME   run one serving bench and print its row as a
-                     ``BENCH_JSON:{...}`` line (subprocess protocol for
-                     the mesh re-exec)
+                     ``BENCH_JSON:{...}`` line
   --spec-smoke       CI gate: assert speedup_gen >= 1.0 on the gated
                      partial-accept mix and write a jax.profiler trace
                      of a speculative step to spec_trace/
@@ -662,62 +656,23 @@ def serving_mesh_shards(cfg, params):
     """Multi-host allocation plane smoke (DESIGN.md §9) — with the
     mesh actually present.  A dp=4 engine only shard_maps over a real
     ("dp",) device mesh when the process has >= 4 devices; below that
-    it silently falls back to vmap semantics, and this bench used to
-    report those single-device numbers as if they measured the mesh.
-    Now a single-device process re-execs itself under
-    ``--xla_force_host_platform_device_count=8`` (the ``--emit-json``
-    subprocess protocol) so the row always comes from a real mesh, and
-    if even the re-exec cannot produce one the row is an explicit
-    ``skipped_no_mesh`` marker instead of misleading numbers."""
-    import subprocess
+    it would fall back to vmap semantics, so the row is an explicit
+    ``skipped_no_mesh`` marker instead of single-device numbers.  Give
+    the process >= 4 devices to measure it (on the CPU,
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8``, as CI's
+    mesh-8 job does).  No child process is started: a parent holding an
+    accelerator would leave the child without one.
 
-    import jax
-    if jax.local_device_count() >= 4:
-        return _serving_mesh_shards_inline(cfg, params)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src")]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    try:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--emit-json", "mesh_shards"],
-            env=env, capture_output=True, text=True, timeout=1200)
-        row = None
-        for line in reversed(proc.stdout.splitlines()):
-            if line.startswith(_JSON_TAG):
-                row = json.loads(line[len(_JSON_TAG):])
-                break
-        if row is None:
-            raise RuntimeError(
-                f"re-exec produced no {_JSON_TAG} row "
-                f"(rc={proc.returncode}): {proc.stderr[-2000:]}")
-        row["mesh_via_subprocess"] = True
-        print(f"serving_mesh_shards,0,devices={row['mesh_devices']} "
-              f"shard_map={row['shard_map']} (via subprocess re-exec) "
-              f"pages_mean_shard={row['pages_mean_shard']} "
-              f"token_identical={row['token_identical_vs_single_device']} "
-              f"leak_free={row['leak_free']}")
-        return row
-    except Exception as e:  # noqa: BLE001 — any failure means "no mesh"
-        row = {"skipped_no_mesh": True,
-               "mesh_devices": jax.local_device_count(),
-               "reason": str(e)[:500]}
-        print(f"serving_mesh_shards,0,skipped_no_mesh=True "
-              f"devices={jax.local_device_count()} (dp=4 would fall back "
-              "to single-device vmap semantics; row suppressed)")
-        return row
-
-
-def _serving_mesh_shards_inline(cfg, params):
-    """The actual dp=4 mesh bench body — caller guarantees >= 4 devices
-    (directly, or via the forced-device re-exec above).  Reports the
-    per-shard occupancy stats from the packed status row (the
-    scheduler's placement balance across hosts) and the usual
+    Reports the per-shard occupancy stats from the packed status row
+    (the scheduler's placement balance across hosts) and the usual
     leak/identity axes vs a single-device run of the same trace."""
+    import jax
+    n_dev = jax.local_device_count()
+    if n_dev < 4:
+        print(f"serving_mesh_shards,0,skipped_no_mesh=True "
+              f"devices={n_dev} (dp=4 would fall back to single-device "
+              "vmap semantics; row suppressed)")
+        return {"skipped_no_mesh": True, "mesh_devices": n_dev}
     import numpy as np
     from repro.serving.engine import Request, ServingEngine
     from repro.serving.sched import SchedConfig
@@ -1299,9 +1254,9 @@ def spec_perf_smoke(cfg, params):
           f"gate_skips={part['gate_skips']} trace_dir=spec_trace")
 
 
-# serving benches reachable via the --emit-json subprocess protocol
+# serving benches runnable alone via --emit-json
 _EMIT_JSON_FNS = {
-    "mesh_shards": _serving_mesh_shards_inline,
+    "mesh_shards": serving_mesh_shards,
     "speculative": serving_speculative,
     "moe": serving_moe,
 }
@@ -1309,11 +1264,14 @@ _EMIT_JSON_FNS = {
 
 def main(argv=None) -> None:
     import argparse
+
+    from repro.launch.compile_cache import init_compile_cache
+    init_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--emit-json", metavar="NAME", default=None,
                     choices=sorted(_EMIT_JSON_FNS),
                     help="run one serving bench and print its row as a "
-                         f"'{_JSON_TAG}' line (subprocess protocol)")
+                         f"'{_JSON_TAG}' line")
     ap.add_argument("--spec-smoke", action="store_true",
                     help="CI gate: assert gated partial-accept "
                          "speedup_gen >= 1.0 and write a jax.profiler "

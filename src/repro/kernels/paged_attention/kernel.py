@@ -32,7 +32,7 @@ def _kernel(table_ref,            # scalar-prefetch: [B, maxp]
             q_ref,                # [1, G, hd]   (block for (b, kh))
             k_ref,                # [1, psz, hd] page tile
             v_ref,                # [1, psz, hd]
-            lens_ref,             # [B] in SMEM-ish (small VMEM block)
+            lens_ref,             # [B] in SMEM
             o_ref,                # [1, G, hd]
             m_scr, l_scr, acc_scr,  # VMEM scratch [G,1],[G,1],[G,hd]
             *, psz: int, scale: float):
@@ -81,7 +81,7 @@ def _chunk_kernel(table_ref,          # scalar-prefetch: [B, maxp]
                   q_ref,              # [1, 1, T*G, hd] (block for (b, kh))
                   k_ref,              # [1, psz, hd] page tile
                   v_ref,              # [1, psz, hd]
-                  lens_ref,           # [B] chunk-base lengths
+                  lens_ref,           # [B] chunk-base lengths, in SMEM
                   o_ref,              # [1, 1, T*G, hd]
                   m_scr, l_scr, acc_scr,  # VMEM scratch [R,1],[R,1],[R,hd]
                   *, psz: int, scale: float, G: int):
@@ -165,9 +165,6 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
     def kv_map(b, h, i, tbl):
         return (jnp.maximum(tbl[b, i], 0) * KH + h, 0, 0)
 
-    def lens_map(b, h, i, tbl):
-        return (0,)
-
     out = pl.pallas_call(
         functools.partial(_chunk_kernel, psz=psz, scale=scale, G=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -177,7 +174,7 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
                 pl.BlockSpec((1, 1, T * G, hd), q_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
-                pl.BlockSpec((B,), lens_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, T * G, hd), q_map),
             scratch_shapes=[
@@ -218,9 +215,6 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
         # mask (page_id < 0) zeroes their contribution
         return (jnp.maximum(tbl[b, i], 0) * KH + h, 0, 0)
 
-    def lens_map(b, h, i, tbl):
-        return (0,)
-
     def o_map(b, h, i, tbl):
         return (b, h, 0, 0)
 
@@ -233,7 +227,7 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                 pl.BlockSpec((1, 1, G, hd), q_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
-                pl.BlockSpec((B,), lens_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, G, hd), o_map),
             scratch_shapes=[

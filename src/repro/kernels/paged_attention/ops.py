@@ -18,10 +18,7 @@ from .ref import paged_attention_ref as _ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("force",))

@@ -73,7 +73,7 @@ def _verify_kernel(pages_ref, lanes_ref, slots_ref,  # scalar-prefetch [NI]
                    q_ref,              # [B, 1, T*G, hd] (block for kh h)
                    k_ref,              # [1, psz, hd] page tile
                    v_ref,              # [1, psz, hd]
-                   lens_ref,           # [B] verify-base lengths
+                   lens_ref,           # [B] verify-base lengths, in SMEM
                    o_ref,              # [B, 1, T*G, hd]
                    m_scr, l_scr, acc_scr,  # VMEM [B*T*G,1],[.,1],[.,hd]
                    *, psz: int, scale: float, G: int, TG: int):
@@ -167,9 +167,6 @@ def verify_attention(q, k_pages, v_pages, page_table, base_lens,
         # per-hot-page property; dead items clamp to resident page 0
         return (jnp.maximum(pages[j], 0) * KH + h, 0, 0)
 
-    def lens_map(h, j, pages, lanes, slots):
-        return (0,)
-
     out = pl.pallas_call(
         functools.partial(_verify_kernel, psz=psz, scale=scale, G=G, TG=TG),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -179,7 +176,7 @@ def verify_attention(q, k_pages, v_pages, page_table, base_lens,
                 pl.BlockSpec((B, 1, TG, hd), q_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
                 pl.BlockSpec((1, psz, hd), kv_map),
-                pl.BlockSpec((B,), lens_map),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((B, 1, TG, hd), q_map),
             scratch_shapes=[

@@ -26,6 +26,7 @@ asserts the run drained with zero leaked pages on surviving shards.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -33,6 +34,7 @@ import numpy as np
 
 from .. import models
 from ..configs import get_config, smoke_config
+from .compile_cache import init_compile_cache
 from ..serving import chaos
 from ..serving.engine import Request, ServingEngine
 from ..serving.sched import SchedConfig
@@ -41,9 +43,19 @@ from ..serving.trace import Tracer
 
 
 def main(argv=None):
+    """Serve a synthetic trace; returns ``(engine, requests)``.
+
+    ``requests`` are the submitted :class:`Request` objects, in rid
+    order, each holding its prompt and served ``out_tokens``.  A run
+    with ``--inject-fault`` may rebuild the engine, which requeues new
+    Request objects; its outcome is in the journal checks printed here.
+    """
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--dtype", choices=("bfloat16", "float32"), default=None,
+                    help="weights and activations (default: the config's)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--dp", type=int, default=2)
@@ -120,6 +132,8 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, dtype=args.dtype)
     params = models.init_params(cfg, jax.random.PRNGKey(0))
     buckets = tuple(int(b) for b in args.chunk_buckets.split(",") if b)
     faults = bool(args.inject_fault)
@@ -146,7 +160,10 @@ def main(argv=None):
             sched=SchedConfig(pin_pages=args.pin_pages,
                               page_budget=args.page_budget,
                               chunk_buckets=buckets),
-            journal=journal, injector=injector, max_restarts=4,
+            journal=journal, injector=injector,
+            # in-place restarts are the fault drill's recovery path; in
+            # a plain run a step error is raised, not retried away
+            max_restarts=4 if faults else 0,
             tracer=tracer, flight=flight)
         if args.flight_recorder:
             install_signal_dump(eng.flight)
@@ -161,7 +178,7 @@ def main(argv=None):
               f"({len(jax.devices())} device(s) for dp={engine.dp})")
     rng = np.random.RandomState(0)
     hot = list(rng.randint(1, cfg.vocab - 1, args.hot_prefix))
-    prompts = []
+    prompts, reqs = [], []
     for rid in range(args.requests):
         slo = ("interactive" if rng.random_sample() < args.interactive_frac
                else "standard")
@@ -178,10 +195,10 @@ def main(argv=None):
             k = max(cfg.moe.top_k, E // 2)
             experts = tuple(
                 int(e) for e in rng.choice(E, size=k, replace=False))
-        engine.submit(Request(rid, prompt=prompt,
-                              max_new_tokens=args.max_new, slo=slo,
-                              deadline_s=args.deadline_s,
-                              experts=experts))
+        reqs.append(Request(rid, prompt=prompt,
+                            max_new_tokens=args.max_new, slo=slo,
+                            deadline_s=args.deadline_s, experts=experts))
+        engine.submit(reqs[-1])
     t0 = time.time()
     crashes = 0
     while True:
@@ -264,7 +281,7 @@ def main(argv=None):
             engine.tracer.write_chrome(args.trace_path)
         print(f"telemetry: {len(engine.tracer.events)} trace events -> "
               f"{args.trace_path}")
-    return engine
+    return engine, reqs
 
 
 if __name__ == "__main__":

@@ -24,6 +24,7 @@ from ..data.pipeline import DataConfig, TokenStream
 from ..optim import adamw
 from ..parallel import compress
 from ..runtime.fault import FailureInjector, FaultTolerantLoop
+from .compile_cache import init_compile_cache
 
 
 def build_step(cfg, opt_cfg, use_compression: bool = False):
@@ -42,6 +43,7 @@ def build_step(cfg, opt_cfg, use_compression: bool = False):
 
 
 def main(argv=None):
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--steps", type=int, default=50)

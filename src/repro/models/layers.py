@@ -27,6 +27,24 @@ def is_def(x) -> bool:
     return isinstance(x, ParamDef)
 
 
+#: axes that stack independent matrices; they are never contracted
+_STACK_AXES = ("layers", "experts")
+
+
+def fan_in(d: ParamDef) -> int:
+    """Inputs each output of ``d`` sums over: its first axis once the
+    stacking axes are dropped — both of ``("heads", "head_dim")`` for
+    attention's output projection.  Counting a stacking axis instead
+    would scale every stacked layer's weights by 1/sqrt(n_layers), and
+    attention scores with them into a near one-hot softmax."""
+    dims = [(n, a) for n, a in zip(d.shape, d.axes) if a not in _STACK_AXES]
+    if not dims:
+        return 1
+    if dims[0][1] == "heads" and len(dims) > 1:
+        return dims[0][0] * dims[1][0]
+    return dims[0][0]
+
+
 def init_tree(key: jax.Array, defs) -> Any:
     """Initialize a pytree of ParamDefs into arrays."""
     leaves, treedef = jax.tree.flatten(defs, is_leaf=is_def)
@@ -38,10 +56,9 @@ def init_tree(key: jax.Array, defs) -> Any:
         elif d.init == "ones":
             arrs.append(jnp.ones(d.shape, d.dtype))
         else:
-            fan_in = d.shape[0] if d.shape else 1
             arrs.append(
                 (jax.random.normal(k, d.shape, jnp.float32)
-                 * (1.0 / math.sqrt(max(fan_in, 1)))).astype(d.dtype))
+                 * (1.0 / math.sqrt(fan_in(d)))).astype(d.dtype))
     return jax.tree.unflatten(treedef, arrs)
 
 

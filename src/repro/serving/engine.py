@@ -85,7 +85,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import NULL, SimContext, WaitFreeAllocator, classed_pool, hier_pool
@@ -509,6 +508,17 @@ def _serve_step(cfg, max_len, eos_id, use_sampler, spec, n_verify, axis_name,
     return state, last_tok, out_count, status
 
 
+def step_collectives(hlo: str) -> Tuple[int, int]:
+    """(all-gathers, other collectives) in compiled HLO text.  A serve
+    step on the dp mesh must show (1, 0): the packed status all_gather
+    is its one collective (DESIGN.md §9)."""
+    n_gather = hlo.count("all-gather(") + hlo.count("all-gather-start(")
+    n_other = sum(hlo.count(c) for c in
+                  ("all-reduce(", "all-reduce-start(", "all-to-all(",
+                   "collective-permute(", "collective-permute-start("))
+    return n_gather, n_other
+
+
 class ServingEngine:
     def __init__(self, cfg, params, dp: int = 1, b_local: int = 4,
                  max_len: int = 512, scheduler_lanes: int = 2,
@@ -682,8 +692,8 @@ class ServingEngine:
             if self.mesh is None:
                 return jax.jit(fn, donate_argnums=donate)
             return jax.jit(
-                shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False),
+                jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                              out_specs=out_specs, check_vma=False),
                 donate_argnums=donate)
 
         eos = -1 if eos_id is None else int(eos_id)
@@ -698,6 +708,10 @@ class ServingEngine:
                 donate=(1, 2, 3))
             for sampler in (False, True) for spec in (False, True)}
         self._sampling_slots: set = set()
+        # (T, spec, use_sampler) of every serve-step variant dispatched:
+        # what :meth:`compiled_step_text` is asked about by checks that
+        # a compiled step holds its kernels and one collective
+        self.step_variants: set = set()
         self._release = wrap(_release_slots, in_specs=(S, R),
                              out_specs=(S, P(None, "dp")), donate=(0,))
         # admission-time CLS_STATE grant (two-class configs): a jitted
@@ -743,6 +757,12 @@ class ServingEngine:
                            in_specs=(S, P(), P("dp")),
                            out_specs=S, donate=(0,))
                 for free in (False, True)}
+
+        if self.mesh is not None:
+            # replicate the weights once: a param tree left on one
+            # device would be broadcast to every mesh device each step
+            self.params = jax.device_put(self.params,
+                                         NamedSharding(self.mesh, P()))
 
         # prefix sharing: only sound when the whole decode state is
         # paged (ring / recurrent layers would need donor state at the
@@ -1643,7 +1663,9 @@ class ServingEngine:
                 gen_slots[slot] = len(dr)
 
         spec = any(gen_slots.values())
-        serve = self._serve_variants[(bool(self._sampling_slots), spec)]
+        sampler = bool(self._sampling_slots)
+        self.step_variants.add((T, spec, sampler))
+        serve = self._serve_variants[(sampler, spec)]
         self.state, self.last_tok, self.out_count, status = serve(
             self.params, self.state, self.last_tok, self.out_count,
             self.budget, self.temps, self.topks, self.seeds,
@@ -1788,6 +1810,23 @@ class ServingEngine:
             self.telemetry.inc("flight_dumps")
             self.tracer.instant("flight_dump", reason="watchdog_timeout")
         return True
+
+    def compiled_step_text(self, T: int, spec: bool = False,
+                           sampler: bool = False) -> str:
+        """Compiled HLO text of the ``(sampler, spec)`` serve-step
+        variant at lane width ``T`` for this engine's state — where a
+        check finds the step's Pallas kernels (``tpu_custom_call``) and
+        counts its collectives (:func:`step_collectives`).  Lowers and
+        compiles without running; the compile caches make a variant the
+        engine already ran cheap."""
+        feed = (jax.ShapeDtypeStruct((self.dp, self.bl, T), jnp.int32),
+                jax.ShapeDtypeStruct((self.dp, self.bl), jnp.int32),
+                jax.ShapeDtypeStruct((self.dp, self.bl), bool),
+                jax.ShapeDtypeStruct((self.dp, self.bl), bool))
+        return self._serve_variants[(sampler, spec)].lower(
+            self.params, self.state, self.last_tok, self.out_count,
+            self.budget, self.temps, self.topks, self.seeds, *feed,
+            self.expert_mask).compile().as_text()
 
     def idle(self) -> bool:
         """Nothing running and nothing admissible: the batch is empty

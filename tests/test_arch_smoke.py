@@ -123,3 +123,17 @@ def test_long_context_support_flags():
                      "mixtral-8x7b"}
     got = {a for a in ARCHS if get_config(a).supports_long}
     assert got == expected_long
+
+
+@pytest.mark.parametrize("shape,axes,fan", [
+    ((4, 256, 8), ("layers", "embed", "mlp"), 256),
+    ((4, 4, 16, 32), ("layers", "heads", "head_dim", "embed"), 64),
+    ((4, 3, 128, 8), ("layers", "experts", "embed", "mlp"), 128),
+], ids=["stacked", "attn_out", "experts"])
+def test_init_scale_is_one_layers_fan_in(shape, axes, fan):
+    """A stacked weight is initialised as one layer's matrix would be:
+    std 1/sqrt(inputs summed), never scaled by the stack depth."""
+    from repro.models.layers import ParamDef, init_tree
+    w = init_tree(jax.random.PRNGKey(0),
+                  {"w": ParamDef(shape, axes, jnp.float32)})["w"]
+    np.testing.assert_allclose(float(jnp.std(w)), fan ** -0.5, rtol=0.1)

@@ -3,10 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:              # container image lacks hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import block_pool, hier_pool, kv_cache
 from repro.core.block_pool import NULL

@@ -30,12 +30,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                    # pragma: no cover
-    import sys
-    sys.path.insert(0, "tests")
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import models
 from repro.configs import get_config, smoke_config

@@ -13,10 +13,7 @@ user writes into live blocks.
 import random
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:              # container image lacks hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (
     SimContext, WaitFreeAllocator, Scheduler, closed_loop,

@@ -38,7 +38,7 @@ from repro.core import classed_pool, refpool
 from repro.core.classed_pool import ClassSpec
 from repro.models.moe import moe_apply
 from repro.models.transformer import EXPERT_PPE, expert_layer_slots
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, step_collectives
 from repro.serving.telemetry import parse_prom
 
 
@@ -326,18 +326,8 @@ def test_one_collective_per_step_expert_paged(moe_setup):
     eng = ServingEngine(cfg, params, dp=2, b_local=2, max_len=64,
                         prefix_sharing=False, expert_paging=True)
     assert eng.mesh is not None
-    hlo = eng._serve_variants[(False, False)].lower(
-        eng.params, eng.state, eng.last_tok, eng.out_count, eng.budget,
-        eng.temps, eng.topks, eng.seeds,
-        jnp.zeros((2, 2, eng.chunk), jnp.int32),
-        jnp.zeros((2, 2), jnp.int32),
-        jnp.zeros((2, 2), bool), jnp.zeros((2, 2), bool),
-        eng.expert_mask,
-    ).compile().as_text()
-    n_gather = hlo.count("all-gather(") + hlo.count("all-gather-start(")
-    n_other = sum(hlo.count(c) for c in
-                  ("all-reduce(", "all-reduce-start(", "all-to-all(",
-                   "collective-permute(", "collective-permute-start("))
+    n_gather, n_other = step_collectives(
+        eng.compiled_step_text(eng.chunk, spec=False))
     assert n_gather == 1, f"expected exactly one all_gather: {n_gather}"
     assert n_other == 0, f"unexpected extra collectives: {n_other}"
 

@@ -3,10 +3,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:              # container image lacks hypothesis
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.paged_attention.kernel import (paged_attention,
                                                   paged_attention_chunk)

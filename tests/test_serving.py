@@ -1,5 +1,7 @@
 """Serving engine tests: continuous batching + allocator integration."""
 
+from pathlib import Path
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -321,3 +323,38 @@ def test_outputs_match_offline_decode(engine_setup):
             if t >= len(toks) - 1:
                 toks.append(nxt)
     assert req.out_tokens == out[:n_new]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_serve_main_returns_served_requests(monkeypatch, tmp_path, dtype):
+    """The serving entry point hands back its engine and requests, serves
+    at the asked dtype, and retries no step in a plain run."""
+    from repro.launch import serve
+    # set from outside, the cache is JAX's own business: nothing in code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    eng, reqs = serve.main(["--arch", "olmo-1b", "--smoke", "--dtype", dtype,
+                            "--requests", "5", "--max-new", "3",
+                            "--dp", "1", "--b-local", "2"])
+    assert eng.cfg.dtype == dtype
+    assert eng.max_restarts == 0
+    assert [r.rid for r in reqs] == list(range(5))
+    assert all(r.done and len(r.out_tokens) == 3 for r in reqs)
+    assert eng.page_occupancy() == 0.0
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.init_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    fixed = compile_cache.init_compile_cache()
+    assert fixed == str(compile_cache.DEFAULT_DIR)
+    # the checkout, found from the module's own path
+    assert compile_cache.DEFAULT_DIR == (
+        Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", fixed)]

@@ -417,15 +417,19 @@ class TestMixedStepDrafts:
         p0 = list(rng.randint(1, 255, 16))
         p1 = list(rng.randint(1, 255, 24))
 
-        def run(speculate):
+        def run(stream=None):
             eng = ServingEngine(cfg, params, dp=1, b_local=2, max_len=64,
-                                chunk_size=4, speculate=speculate,
+                                chunk_size=4, speculate=stream is not None,
                                 draft_len=3)
-            if speculate:
+            if stream is not None:
+                # drafts that match, whatever the random model emits:
+                # r0's own non-speculative continuation
                 key = eng.spec_store.key_of(p0)
                 eng.spec_store.record(
-                    key, tuple(p0[len(key):]) + tuple(range(30, 50)))
-            r0 = Request(0, prompt=list(p0), max_new_tokens=8)
+                    key, tuple(p0[len(key):]) + tuple(stream))
+            # long enough that r0 is still decoding when r1 arrives,
+            # even with every draft accepted
+            r0 = Request(0, prompt=list(p0), max_new_tokens=24)
             r1 = Request(1, prompt=list(p1), max_new_tokens=4)
             eng.submit(r0)
             # r0 prefills (and starts decoding) alone, then r1's long
@@ -437,8 +441,8 @@ class TestMixedStepDrafts:
             assert r0.done and r1.done
             return [r0.out_tokens, r1.out_tokens], eng
 
-        ref, _ = run(speculate=False)
-        out, eng = run(speculate=True)
+        ref, _ = run()
+        out, eng = run(stream=ref[0])
         assert out == ref, "a draft riding a prefill step changed tokens"
         assert eng.stats["spec_mixed_steps"] > 0, (
             "no draft ever rode a mixed prompt/decode step — the "

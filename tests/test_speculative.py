@@ -14,7 +14,7 @@ from repro.configs import get_config, smoke_config
 from repro.core import hier_pool, kv_cache
 from repro.core.linearizability import check_speculative_history
 from repro.core.sim import OpRecord
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, step_collectives
 from repro.serving.sched import SchedConfig
 
 
@@ -548,18 +548,8 @@ def test_speculative_step_one_sync_one_collective(engine_setup):
     assert eng.stats["spec_lanes"] > 0, "steps were not speculative"
 
     # exactly one collective in the compiled speculative step
-    hlo = eng._serve_variants[(False, True)].lower(
-        eng.params, eng.state, eng.last_tok, eng.out_count, eng.budget,
-        eng.temps, eng.topks, eng.seeds,
-        jnp.zeros((4, 2, eng._spec_T), jnp.int32),
-        jnp.zeros((4, 2), jnp.int32),
-        jnp.zeros((4, 2), bool), jnp.zeros((4, 2), bool),
-        eng.expert_mask,
-    ).compile().as_text()
-    n_gather = hlo.count("all-gather(") + hlo.count("all-gather-start(")
-    n_other = sum(hlo.count(c) for c in
-                  ("all-reduce(", "all-reduce-start(", "all-to-all(",
-                   "collective-permute(", "collective-permute-start("))
+    n_gather, n_other = step_collectives(
+        eng.compiled_step_text(eng._spec_T, spec=True))
     assert n_gather == 1, f"expected exactly one all_gather, HLO has {n_gather}"
     assert n_other == 0, "unexpected extra collectives in the step"
 

@@ -22,7 +22,7 @@ import pytest
 from repro import models
 from repro.configs import get_config, smoke_config
 from repro.serving import chaos
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, step_collectives
 from repro.serving.telemetry import (CTR_ALLOC, CTR_DRAIN, CTR_FREED,
                                      CTR_MARGIN, CTR_REFILL, CTR_ROLLBACK,
                                      CTR_SHARED_FREE, CTR_SPILL, N_CTR,
@@ -552,18 +552,8 @@ def test_one_collective_per_step_with_telemetry(engine_setup):
     cfg, params = engine_setup
     eng = ServingEngine(cfg, params, dp=4, b_local=2, max_len=64)
     assert eng.mesh is not None
-    hlo = eng._serve_variants[(False, False)].lower(
-        eng.params, eng.state, eng.last_tok, eng.out_count, eng.budget,
-        eng.temps, eng.topks, eng.seeds,
-        jnp.zeros((4, 2, eng.chunk), jnp.int32),
-        jnp.zeros((4, 2), jnp.int32),
-        jnp.zeros((4, 2), bool), jnp.zeros((4, 2), bool),
-        eng.expert_mask,
-    ).compile().as_text()
-    n_gather = hlo.count("all-gather(") + hlo.count("all-gather-start(")
-    n_other = sum(hlo.count(c) for c in
-                  ("all-reduce(", "all-reduce-start(", "all-to-all(",
-                   "collective-permute(", "collective-permute-start("))
+    n_gather, n_other = step_collectives(
+        eng.compiled_step_text(eng.chunk, spec=False))
     assert n_gather == 1, f"expected exactly one all_gather: {n_gather}"
     assert n_other == 0, f"unexpected extra collectives: {n_other}"
 
