@@ -32,6 +32,7 @@ from typing import NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..serving.trace import scoped
 from . import hier_pool
 from .hier_pool import HierPool
 
@@ -113,14 +114,18 @@ def create_dp(dp: int, specs: Sequence[ClassSpec]) -> ClassedPool:
 #
 # Thin static-dispatch wrappers: extract class ``cls``, run the
 # single-class op, put the result back.  Only the touched class's
-# leaves appear in the lowered HLO.
+# leaves appear in the lowered HLO.  Every device op here runs under
+# the ``alloc`` device scope (serving/trace.py), so a profile gives the
+# allocator's own device time wherever it is called from.
 
+@scoped("alloc")
 def alloc_n_dp(pool: ClassedPool, cls: int, counts: jax.Array,
                max_per_lane: int) -> Tuple[ClassedPool, jax.Array]:
     hp, ids = hier_pool.alloc_n_dp(pool.classes[cls], counts, max_per_lane)
     return _put(pool, cls, hp), ids
 
 
+@scoped("alloc")
 def alloc_n_or_shared_dp(pool: ClassedPool, cls: int, counts: jax.Array,
                          max_per_lane: int
                          ) -> Tuple[ClassedPool, jax.Array]:
@@ -129,6 +134,7 @@ def alloc_n_or_shared_dp(pool: ClassedPool, cls: int, counts: jax.Array,
     return _put(pool, cls, hp), ids
 
 
+@scoped("alloc")
 def alloc_from_shared_dp(pool: ClassedPool, cls: int, counts: jax.Array,
                          max_per_lane: int
                          ) -> Tuple[ClassedPool, jax.Array]:
@@ -137,25 +143,30 @@ def alloc_from_shared_dp(pool: ClassedPool, cls: int, counts: jax.Array,
     return _put(pool, cls, hp), ids
 
 
+@scoped("alloc")
 def free_n_dp(pool: ClassedPool, cls: int, ids: jax.Array) -> ClassedPool:
     return _put(pool, cls, hier_pool.free_n_dp(pool.classes[cls], ids))
 
 
+@scoped("alloc")
 def free_n_metered_dp(pool: ClassedPool, cls: int, ids: jax.Array
                       ) -> Tuple[ClassedPool, jax.Array]:
     hp, spilled = hier_pool.free_n_metered_dp(pool.classes[cls], ids)
     return _put(pool, cls, hp), spilled
 
 
+@scoped("alloc")
 def free_shared_dp(pool: ClassedPool, cls: int,
                    ids: jax.Array) -> ClassedPool:
     return _put(pool, cls, hier_pool.free_shared_dp(pool.classes[cls], ids))
 
 
+@scoped("alloc")
 def addref_dp(pool: ClassedPool, cls: int, ids: jax.Array) -> ClassedPool:
     return _put(pool, cls, hier_pool.addref_dp(pool.classes[cls], ids))
 
 
+@scoped("alloc")
 def rebalance_dp(pool: ClassedPool,
                  cls: Optional[int] = None) -> ClassedPool:
     """Deamortized rebalance — all classes (default) in one call, so
@@ -167,6 +178,7 @@ def rebalance_dp(pool: ClassedPool,
         hier_pool.rebalance_dp(hp) for hp in pool.classes))
 
 
+@scoped("alloc")
 def rebalance_drain_dp(pool: ClassedPool,
                        cls: Optional[int] = None) -> ClassedPool:
     if cls is not None:
@@ -176,6 +188,7 @@ def rebalance_drain_dp(pool: ClassedPool,
         hier_pool.rebalance_drain_dp(hp) for hp in pool.classes))
 
 
+@scoped("alloc")
 def rebalance_refill_dp(pool: ClassedPool,
                         cls: Optional[int] = None) -> ClassedPool:
     if cls is not None:
@@ -187,10 +200,12 @@ def rebalance_refill_dp(pool: ClassedPool,
 
 # ------------------------------------------------------------- queries
 
+@scoped("alloc")
 def free_per_shard(pool: ClassedPool, cls: int) -> jax.Array:
     return hier_pool.free_per_shard(pool.classes[cls])
 
 
+@scoped("alloc")
 def live_per_shard(pool: ClassedPool, cls: int) -> jax.Array:
     return hier_pool.live_per_shard(pool.classes[cls])
 
@@ -204,11 +219,13 @@ def pages_local(pool: ClassedPool, cls: int) -> int:
     return pool.classes[cls].shared.free_ids.shape[-1]
 
 
+@scoped("alloc")
 def total_free(pool: ClassedPool) -> jax.Array:
     """Free blocks summed over ALL classes (and shards)."""
     return sum(hier_pool.total_free(hp) for hp in pool.classes)
 
 
+@scoped("alloc")
 def num_live(pool: ClassedPool) -> jax.Array:
     """Live blocks summed over ALL classes (and shards)."""
     return sum(hier_pool.num_live(hp) for hp in pool.classes)

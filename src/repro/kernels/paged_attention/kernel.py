@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...serving.trace import scope
+
 NEG_INF = -1e30
 
 
@@ -154,8 +156,9 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
     # [B, T, KH, G, hd] -> [B, KH, T*G, hd]: row r = t * G + g
     qg = q.reshape(B, T, KH, G, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KH, T * G, hd)
-    kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-    vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    with scope("kv_layout"):     # the whole pool, every call
+        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
 
     grid = (B, KH, maxp)
 
@@ -202,8 +205,9 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
 
     # layout: group q by kv head -> [B, KH, G, hd]; pages to [P*? ] tiles
     qg = q.reshape(B, KH, G, hd)
-    kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-    vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    with scope("kv_layout"):     # the whole pool, every call
+        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
 
     grid = (B, KH, maxp)
 

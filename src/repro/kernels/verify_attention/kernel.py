@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...serving.trace import scope
+
 NEG_INF = -1e30
 
 
@@ -153,8 +155,9 @@ def verify_attention(q, k_pages, v_pages, page_table, base_lens,
     # [B, T, KH, G, hd] -> [B, KH, T*G, hd]: row r = t * G + g
     qg = q.reshape(B, T, KH, G, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KH, TG, hd)
-    kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-    vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    with scope("kv_layout"):     # the whole pool, every call
+        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
 
     grid = (KH, n_items)
 

@@ -44,6 +44,7 @@ from ..core.classed_pool import CLS_KV, ClassSpec
 from ..kernels.paged_attention.ops import paged_attention_chunk
 from ..kernels.verify_attention.ops import verify_attention
 from ..parallel.partition import constrain_batch
+from ..serving.trace import scope, scoped
 from . import attention as attn
 from . import moe as moe_mod
 from . import rglru as rglru_mod
@@ -530,6 +531,7 @@ def _rec_state_defs(cfg, kind, stack, dp, b_local):
 
 # ======================================================= chunked decode path
 
+@scoped("kv_write")
 def _paged_write_chunk(k_pages, v_pages, k_new, v_new, page_ids, pos_in_page,
                        write):
     """k_pages: [DP, P, psz, KH, hd]; k_new: [DP, Bl, T, KH, hd];
@@ -560,15 +562,18 @@ def _paged_attn_chunk(q, k_pages, v_pages, tables, base, verify=False):
     DP, Bl, T, H, hd = q.shape
     P = k_pages.shape[1]
     maxp = tables.shape[2]
-    off = (jnp.arange(DP, dtype=jnp.int32) * P)[:, None, None]
-    tg = jnp.where(tables >= 0, tables + off, -1).reshape(DP * Bl, maxp)
-    kg = k_pages.reshape((DP * P,) + k_pages.shape[2:])
-    vg = v_pages.reshape((DP * P,) + v_pages.shape[2:])
+    with scope("kv_layout"):
+        off = (jnp.arange(DP, dtype=jnp.int32) * P)[:, None, None]
+        tg = jnp.where(tables >= 0, tables + off, -1)
+        tg = tg.reshape(DP * Bl, maxp)
+        kg = k_pages.reshape((DP * P,) + k_pages.shape[2:])
+        vg = v_pages.reshape((DP * P,) + v_pages.shape[2:])
     op = verify_attention if verify else paged_attention_chunk
     o = op(q.reshape(DP * Bl, T, H, hd), kg, vg, tg, base.reshape(DP * Bl))
     return o.reshape(DP, Bl, T, H, hd)
 
 
+@scoped("kv_write")
 def _ring_write_chunk(k_ring, v_ring, k_new, v_new, positions, tok_valid,
                       lens):
     """Write a chunk into the rings.  positions/tok_valid: [DP, Bl, T].
@@ -675,36 +680,37 @@ def _mix_decode_chunk(cfg, lp, x, kind, st_kind, layer_state, state,
     kind = base_kind(kind)
     h = apply_norm(cfg, lp["norm1"], x)
     if kind in ("global", "local"):
-        hf = h.reshape(DP * Bl, T, d)
-        pos_flat = positions.reshape(DP * Bl, T)
-        q = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wv"])
-        q = apply_rope(q, pos_flat, cfg.rope_theta)
-        k = apply_rope(k, pos_flat, cfg.rope_theta)
-        qd = q.reshape(DP, Bl, T, cfg.n_heads, cfg.hd)
-        kd = k.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
-        vd = v.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
-        if st_kind == "paged":
-            kp, vp = layer_state
-            psz = cfg.page_size
-            maxp = state.page_tables.shape[2]
-            pid = jnp.take_along_axis(
-                state.page_tables, jnp.minimum(positions // psz, maxp - 1),
-                axis=2)
-            write = tok_valid & (pid >= 0)
-            kp, vp = _paged_write_chunk(kp, vp, kd, vd, pid,
-                                        positions % psz, write)
-            o = _paged_attn_chunk(qd, kp, vp, state.page_tables, base,
-                                  verify=verify)
-            new_state = (kp, vp)
-        else:
-            kr, vr = layer_state
-            o = _ring_attn_chunk(cfg, qd, kr, vr, kd, vd, base, lens)
-            kr, vr = _ring_write_chunk(kr, vr, kd, vd, positions, tok_valid,
-                                       lens)
-            new_state = (kr, vr)
-        x = x + jnp.einsum("xbthk,hkd->xbtd", o, lp["attn"]["wo"])
+        with scope("attn"):
+            hf = h.reshape(DP * Bl, T, d)
+            pos_flat = positions.reshape(DP * Bl, T)
+            q = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wq"])
+            k = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", hf, lp["attn"]["wv"])
+            q = apply_rope(q, pos_flat, cfg.rope_theta)
+            k = apply_rope(k, pos_flat, cfg.rope_theta)
+            qd = q.reshape(DP, Bl, T, cfg.n_heads, cfg.hd)
+            kd = k.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
+            vd = v.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
+            if st_kind == "paged":
+                kp, vp = layer_state
+                psz = cfg.page_size
+                maxp = state.page_tables.shape[2]
+                pid = jnp.take_along_axis(
+                    state.page_tables, jnp.minimum(positions // psz, maxp - 1),
+                    axis=2)
+                write = tok_valid & (pid >= 0)
+                kp, vp = _paged_write_chunk(kp, vp, kd, vd, pid,
+                                            positions % psz, write)
+                o = _paged_attn_chunk(qd, kp, vp, state.page_tables, base,
+                                      verify=verify)
+                new_state = (kp, vp)
+            else:
+                kr, vr = layer_state
+                o = _ring_attn_chunk(cfg, qd, kr, vr, kd, vd, base, lens)
+                kr, vr = _ring_write_chunk(kr, vr, kd, vd, positions,
+                                           tok_valid, lens)
+                new_state = (kr, vr)
+            x = x + jnp.einsum("xbthk,hkd->xbtd", o, lp["attn"]["wo"])
     else:  # ssd / rglru — sequential recurrence, scanned over the chunk
         def tok_body(st, inp):
             ht, valid_t = inp                      # [DP,Bl,d], [DP,Bl]
@@ -740,35 +746,36 @@ def _mix_decode_chunk(cfg, lp, x, kind, st_kind, layer_state, state,
 
     moe_meta = None
     if "ffn" in lp:
-        h2 = apply_norm(cfg, lp["norm2"], x)
-        h2f = h2.reshape(DP * Bl, T, d)
-        if "router" in lp["ffn"]:
-            E = cfg.moe.num_experts
-            eff = lp["ffn"]
-            if expert_buf is not None:
-                # paged experts: rebuild the stacked [E, ...] weight
-                # views from the gathered CLS_EXPERT pages and run the
-                # IDENTICAL dispatch path.  Non-resident experts gather
-                # page 0 (finite garbage) — the footprint mask keeps
-                # every valid token off them, and dropped/invalid rows
-                # contribute exactly 0 by the dispatch masking.
-                ff = cfg.d_ff
-                eff = {
-                    "router": lp["ffn"]["router"],
-                    "w_gate": expert_buf[:, 0].reshape(E, d, ff),
-                    "w_up": expert_buf[:, 1].reshape(E, d, ff),
-                    "w_down": expert_buf[:, 2].reshape(E, ff, d),
-                }
-            mask = (None if expert_mask is None
-                    else expert_mask.reshape(DP * Bl, E))
-            f, dropped, routed = moe_mod.moe_apply(
-                cfg, eff, h2f, expert_mask=mask,
-                token_valid=tok_valid.reshape(DP * Bl, T), metered=True)
-            moe_meta = (dropped.reshape(DP, Bl).sum(axis=1),
-                        routed.reshape(DP, Bl, E).sum(axis=1))
-        else:
-            f = ffn_apply(cfg, lp["ffn"], h2f)
-        x = x + f.reshape(DP, Bl, T, d)
+        with scope("ffn"):
+            h2 = apply_norm(cfg, lp["norm2"], x)
+            h2f = h2.reshape(DP * Bl, T, d)
+            if "router" in lp["ffn"]:
+                E = cfg.moe.num_experts
+                eff = lp["ffn"]
+                if expert_buf is not None:
+                    # paged experts: rebuild the stacked [E, ...] weight
+                    # views from the gathered CLS_EXPERT pages and run the
+                    # IDENTICAL dispatch path.  Non-resident experts gather
+                    # page 0 (finite garbage) — the footprint mask keeps
+                    # every valid token off them, and dropped/invalid rows
+                    # contribute exactly 0 by the dispatch masking.
+                    ff = cfg.d_ff
+                    eff = {
+                        "router": lp["ffn"]["router"],
+                        "w_gate": expert_buf[:, 0].reshape(E, d, ff),
+                        "w_up": expert_buf[:, 1].reshape(E, d, ff),
+                        "w_down": expert_buf[:, 2].reshape(E, ff, d),
+                    }
+                mask = (None if expert_mask is None
+                        else expert_mask.reshape(DP * Bl, E))
+                f, dropped, routed = moe_mod.moe_apply(
+                    cfg, eff, h2f, expert_mask=mask,
+                    token_valid=tok_valid.reshape(DP * Bl, T), metered=True)
+                moe_meta = (dropped.reshape(DP, Bl).sum(axis=1),
+                            routed.reshape(DP, Bl, E).sum(axis=1))
+            else:
+                f = ffn_apply(cfg, lp["ffn"], h2f)
+            x = x + f.reshape(DP, Bl, T, d)
     return x, new_state, moe_meta
 
 
@@ -829,23 +836,24 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
         # that would overflow the page table, or whose pages the pool
         # denies, appends NOTHING — without the gate the page-index
         # clamp below would overwrite live KV while seq_lens advanced
-        psz = cfg.page_size
-        maxp = state.page_tables.shape[2]
-        kmax = -(-T // psz)
-        lens, pages_before, counts = block_pool.chunk_page_plan(
-            base, lens, psz, maxp)
-        pool, got = classed_pool.alloc_n_or_shared_dp(
-            state.pool, CLS_KV, counts, kmax)
-        lens = jnp.where(block_pool.granted_mask(got, counts), lens, 0)
-        dp_i = jnp.arange(DP)[:, None, None]
-        bl_i = jnp.arange(Bl)[None, :, None]
-        kk = jnp.arange(kmax)[None, None, :]
-        slot = pages_before[..., None] + kk
-        new_page = (kk < counts[..., None]) & (got >= 0)
-        slot = jnp.where(new_page, slot, maxp)       # out-of-range => drop
-        new_tables = state.page_tables.at[dp_i, bl_i, slot].set(
-            got, mode="drop")
-        state = state._replace(page_tables=new_tables, pool=pool)
+        with scope("alloc"):
+            psz = cfg.page_size
+            maxp = state.page_tables.shape[2]
+            kmax = -(-T // psz)
+            lens, pages_before, counts = block_pool.chunk_page_plan(
+                base, lens, psz, maxp)
+            pool, got = classed_pool.alloc_n_or_shared_dp(
+                state.pool, CLS_KV, counts, kmax)
+            lens = jnp.where(block_pool.granted_mask(got, counts), lens, 0)
+            dp_i = jnp.arange(DP)[:, None, None]
+            bl_i = jnp.arange(Bl)[None, :, None]
+            kk = jnp.arange(kmax)[None, None, :]
+            slot = pages_before[..., None] + kk
+            new_page = (kk < counts[..., None]) & (got >= 0)
+            slot = jnp.where(new_page, slot, maxp)       # out-of-range => drop
+            new_tables = state.page_tables.at[dp_i, bl_i, slot].set(
+                got, mode="drop")
+            state = state._replace(page_tables=new_tables, pool=pool)
 
     positions = base[..., None] + jnp.arange(T, dtype=jnp.int32)[None, None]
     tok_valid = jnp.arange(T)[None, None, :] < lens[..., None]
@@ -931,9 +939,12 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
                         state.enc_kv[1][:cfg.n_groups])
         else:
             enc_scan = (jnp.zeros((cfg.n_groups,)),) * 2  # placeholder
-        (x, _, meters), new_gstates = jax.lax.scan(
-            group_body, (x, ebuf0, meters),
-            (params["groups"], gstates, enc_scan, etab_pat, etab_next))
+        # ``layers``: the scan's own per-layer slicing of the stacked
+        # weights and states and their write-back carry no inner scope
+        with scope("layers"):
+            (x, _, meters), new_gstates = jax.lax.scan(
+                group_body, (x, ebuf0, meters),
+                (params["groups"], gstates, enc_scan, etab_pat, etab_next))
     else:
         new_gstates = {}
 
@@ -945,7 +956,8 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
                    else "ring" if bk == "local" else "rec")
         ls = (state.kv_pages.get(pos) or state.rings.get(pos)
               or state.rec.get(pos))
-        ls0 = jax.tree.map(lambda a: a[0], ls)
+        with scope("layers"):
+            ls0 = jax.tree.map(lambda a: a[0], ls)
         lp = params["rem"][f"pos{j}"]
         enc_l = None
         if has_x and state.enc_kv is not None:
@@ -960,7 +972,8 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
                                         lens, enc_l, verify=verify,
                                         expert_buf=ebuf_r,
                                         expert_mask=expert_mask)
-        new_rem_states[pos] = jax.tree.map(lambda a: a[None], ns)
+        with scope("layers"):
+            new_rem_states[pos] = jax.tree.map(lambda a: a[None], ns)
         meters = absorb(meters, meta, tab_r)
 
     kv_pages, rings, rec = {}, {}, {}

@@ -111,7 +111,7 @@ from .telemetry import (CTR_ALLOC, CTR_DRAIN, CTR_EDROP, CTR_EHIT, CTR_EMISS,
                         CTR_EPREF, CTR_FREED, CTR_MARGIN, CTR_REFILL,
                         CTR_ROLLBACK, CTR_SHARED_FREE, CTR_SPILL, N_CTR,
                         FlightRecorder, Telemetry)
-from .trace import Tracer
+from .trace import Tracer, scope
 
 
 @dataclasses.dataclass
@@ -317,119 +317,121 @@ def _serve_step(cfg, max_len, eos_id, use_sampler, spec, n_verify, axis_name,
     ctr_alloc = [free_in[c] - free_fwd[c] for c in range(C)]
     idx = jnp.maximum(feed_lens - 1, 0)
     emit = emit & active
-    if spec:
-        # --- projection slimming (DESIGN.md §12): only the k + 1
-        # verify positions of a draft lane need logits.  Gather those
-        # hidden rows FIRST — a generating lane's verify positions are
-        # lane positions 0..Tv-1, a prompt lane needs only its single
-        # emitting position idx (broadcast over the gathered rows) —
-        # then project the [DP, Bl, Tv, d] gather instead of the whole
-        # [DP, Bl, T, d] lane, so a draft riding a chunk-width step
-        # pays k + 1 vocab columns per slot, never T
-        Tv = min(T, n_verify) if n_verify > 0 else T
-        j = jnp.arange(Tv, dtype=jnp.int32)
-        vpos = jnp.where(is_prompt[..., None], idx[..., None],
-                         jnp.minimum(j, T - 1)[None, None])  # [DP,Bl,Tv]
-        hidden_v = jnp.take_along_axis(hidden, vpos[..., None], axis=2)
-        # output-key index per position: generating lanes emit from
-        # position 0 on (key out_count + i); a prompt lane's single
-        # emitting position is output index 0 (key out_count) — the
-        # gather changes WHICH rows are scored, never the key a given
-        # output index draws with, so the fold_in(seed, out_count + i)
-        # stream stays bit-exact
-        cnt = out_count[..., None] + jnp.where(is_prompt[..., None], 0,
-                                               j[None, None])
-        if use_sampler:
-            logits = logits_apply(cfg, params["embed"],
-                                  hidden_v)            # [DP,Bl,Tv,V]
-            nxt_all = sample_lane(logits, temps, topks, seeds, cnt)
+    # projection, sampling, verification (the step's ``head`` scope)
+    with scope("head"):
+        if spec:
+            # --- projection slimming (DESIGN.md §12): only the k + 1
+            # verify positions of a draft lane need logits.  Gather those
+            # hidden rows FIRST — a generating lane's verify positions are
+            # lane positions 0..Tv-1, a prompt lane needs only its single
+            # emitting position idx (broadcast over the gathered rows) —
+            # then project the [DP, Bl, Tv, d] gather instead of the whole
+            # [DP, Bl, T, d] lane, so a draft riding a chunk-width step
+            # pays k + 1 vocab columns per slot, never T
+            Tv = min(T, n_verify) if n_verify > 0 else T
+            j = jnp.arange(Tv, dtype=jnp.int32)
+            vpos = jnp.where(is_prompt[..., None], idx[..., None],
+                             jnp.minimum(j, T - 1)[None, None])  # [DP,Bl,Tv]
+            hidden_v = jnp.take_along_axis(hidden, vpos[..., None], axis=2)
+            # output-key index per position: generating lanes emit from
+            # position 0 on (key out_count + i); a prompt lane's single
+            # emitting position is output index 0 (key out_count) — the
+            # gather changes WHICH rows are scored, never the key a given
+            # output index draws with, so the fold_in(seed, out_count + i)
+            # stream stays bit-exact
+            cnt = out_count[..., None] + jnp.where(is_prompt[..., None], 0,
+                                                   j[None, None])
+            if use_sampler:
+                logits = logits_apply(cfg, params["embed"],
+                                      hidden_v)            # [DP,Bl,Tv,V]
+                nxt_all = sample_lane(logits, temps, topks, seeds, cnt)
+            else:
+                # chunked-vocab argmax: greedy verification never builds
+                # the [Tv, V] tensor either
+                nxt_all = logits_argmax_chunked(cfg, params["embed"], hidden_v)
+            # a prompt lane's gathered rows all hold position idx, so row 0
+            # is its emitting candidate; a generating lane's last fed
+            # position is row feed_lens - 1 (feed <= Tv by dispatch)
+            vidx = jnp.where(is_prompt, 0, jnp.minimum(idx, Tv - 1))
+            last_pos = jnp.take_along_axis(nxt_all, vidx[..., None],
+                                           axis=2)[..., 0]
+            # emission stream: generating lanes emit candidates in lane
+            # order; prompt lanes emit (at most) their last position's
+            etoks = jnp.where(is_prompt[..., None], last_pos[..., None],
+                              nxt_all)
+            # draft i (lane position i >= 1) is accepted iff position i-1's
+            # candidate equals it and every earlier draft was accepted
+            dmatch = ((nxt_all[..., :-1] == toks[..., 1:Tv]) &
+                      (j[None, None, 1:] < feed_lens[..., None]))
+            accepted = jnp.sum(jnp.cumprod(dmatch.astype(jnp.int32), axis=-1),
+                               axis=-1)
+            n_cand = (jnp.where(is_prompt, 1, accepted + 1)
+                      * emit.astype(jnp.int32))
+            # EOS / budget truncate the emission stream (an emitted EOS is
+            # included, then the slot finishes)
+            is_e = (etoks == eos_id) & (j[None, None] < n_cand[..., None])
+            eos_cut = jnp.where(jnp.any(is_e, axis=-1),
+                                jnp.argmax(is_e, axis=-1) + 1, Tv + 1)
+            room = jnp.maximum(budget - out_count, 0)
+            n_emit = jnp.minimum(n_cand, jnp.minimum(room, eos_cut))
+            hit_eos = jnp.any(is_e & (j[None, None] < n_emit[..., None]),
+                              axis=-1)
+            # --- rollback: keep last_tok + accepted drafts, free the
+            # rejected tail's whole-page over-allocation back to the slot's
+            # OWN lane (same-shard by construction; refcount 1 pages —
+            # granted this very step — so free_n restacks them)
+            adv = state.seq_lens - base
+            n_keep = jnp.where(is_prompt, adv, jnp.minimum(n_emit, adv))
+            psz = cfg.page_size
+            maxp = state.page_tables.shape[2]
+            keep_pages = (base + n_keep + psz - 1) // psz
+            have_pages = (base + adv + psz - 1) // psz
+            kidx = jnp.arange(maxp, dtype=jnp.int32)[None, None, :]
+            roll = ((kidx >= keep_pages[..., None]) &
+                    (kidx < have_pages[..., None]))
+            pool, spill_roll = classed_pool.free_n_metered_dp(
+                state.pool, CLS_KV, jnp.where(roll, state.page_tables, NULL))
+            state = state._replace(
+                pool=pool,
+                page_tables=jnp.where(roll, NULL, state.page_tables),
+                seq_lens=base + n_keep)
+            # rollback pages are refcount-1 by construction (granted this
+            # very step), so the free-level delta counts them exactly;
+            # rollback is KV-class traffic only
+            ctr_roll = [classed_pool.free_per_shard(state.pool, CLS_KV)
+                        - free_fwd[CLS_KV]]
+            ctr_roll += [jnp.zeros_like(ctr_roll[0]) for _ in range(C - 1)]
+            out_count = out_count + n_emit
+            seq_full = state.seq_lens >= max_len - 1
+            done = active & ((out_count >= budget) | seq_full | hit_eos)
+            last_emitted = jnp.take_along_axis(
+                etoks, jnp.maximum(n_emit - 1, 0)[..., None], axis=2)[..., 0]
+            last_tok = jnp.where(n_emit > 0, last_emitted, last_tok)
+            tok_rows = jnp.where(j[None, None] < n_emit[..., None], etoks, -1)
+            if Tv < T:      # pad the gathered rows back to the lane width
+                tok_rows = jnp.concatenate(
+                    [tok_rows, jnp.full((DP, Bl, T - Tv), -1, jnp.int32)],
+                    axis=-1)
         else:
-            # chunked-vocab argmax: greedy verification never builds
-            # the [Tv, V] tensor either
-            nxt_all = logits_argmax_chunked(cfg, params["embed"], hidden_v)
-        # a prompt lane's gathered rows all hold position idx, so row 0
-        # is its emitting candidate; a generating lane's last fed
-        # position is row feed_lens - 1 (feed <= Tv by dispatch)
-        vidx = jnp.where(is_prompt, 0, jnp.minimum(idx, Tv - 1))
-        last_pos = jnp.take_along_axis(nxt_all, vidx[..., None],
-                                       axis=2)[..., 0]
-        # emission stream: generating lanes emit candidates in lane
-        # order; prompt lanes emit (at most) their last position's
-        etoks = jnp.where(is_prompt[..., None], last_pos[..., None],
-                          nxt_all)
-        # draft i (lane position i >= 1) is accepted iff position i-1's
-        # candidate equals it and every earlier draft was accepted
-        dmatch = ((nxt_all[..., :-1] == toks[..., 1:Tv]) &
-                  (j[None, None, 1:] < feed_lens[..., None]))
-        accepted = jnp.sum(jnp.cumprod(dmatch.astype(jnp.int32), axis=-1),
-                           axis=-1)
-        n_cand = (jnp.where(is_prompt, 1, accepted + 1)
-                  * emit.astype(jnp.int32))
-        # EOS / budget truncate the emission stream (an emitted EOS is
-        # included, then the slot finishes)
-        is_e = (etoks == eos_id) & (j[None, None] < n_cand[..., None])
-        eos_cut = jnp.where(jnp.any(is_e, axis=-1),
-                            jnp.argmax(is_e, axis=-1) + 1, Tv + 1)
-        room = jnp.maximum(budget - out_count, 0)
-        n_emit = jnp.minimum(n_cand, jnp.minimum(room, eos_cut))
-        hit_eos = jnp.any(is_e & (j[None, None] < n_emit[..., None]),
-                          axis=-1)
-        # --- rollback: keep last_tok + accepted drafts, free the
-        # rejected tail's whole-page over-allocation back to the slot's
-        # OWN lane (same-shard by construction; refcount 1 pages —
-        # granted this very step — so free_n restacks them)
-        adv = state.seq_lens - base
-        n_keep = jnp.where(is_prompt, adv, jnp.minimum(n_emit, adv))
-        psz = cfg.page_size
-        maxp = state.page_tables.shape[2]
-        keep_pages = (base + n_keep + psz - 1) // psz
-        have_pages = (base + adv + psz - 1) // psz
-        kidx = jnp.arange(maxp, dtype=jnp.int32)[None, None, :]
-        roll = ((kidx >= keep_pages[..., None]) &
-                (kidx < have_pages[..., None]))
-        pool, spill_roll = classed_pool.free_n_metered_dp(
-            state.pool, CLS_KV, jnp.where(roll, state.page_tables, NULL))
-        state = state._replace(
-            pool=pool,
-            page_tables=jnp.where(roll, NULL, state.page_tables),
-            seq_lens=base + n_keep)
-        # rollback pages are refcount-1 by construction (granted this
-        # very step), so the free-level delta counts them exactly;
-        # rollback is KV-class traffic only
-        ctr_roll = [classed_pool.free_per_shard(state.pool, CLS_KV)
-                    - free_fwd[CLS_KV]]
-        ctr_roll += [jnp.zeros_like(ctr_roll[0]) for _ in range(C - 1)]
-        out_count = out_count + n_emit
-        seq_full = state.seq_lens >= max_len - 1
-        done = active & ((out_count >= budget) | seq_full | hit_eos)
-        last_emitted = jnp.take_along_axis(
-            etoks, jnp.maximum(n_emit - 1, 0)[..., None], axis=2)[..., 0]
-        last_tok = jnp.where(n_emit > 0, last_emitted, last_tok)
-        tok_rows = jnp.where(j[None, None] < n_emit[..., None], etoks, -1)
-        if Tv < T:      # pad the gathered rows back to the lane width
+            # no drafts, no rollback
+            ctr_roll = [jnp.zeros_like(f) for f in free_in]
+            spill_roll = jnp.zeros_like(free_in[CLS_KV])
+            h_last = jnp.take_along_axis(hidden, idx[..., None, None],
+                                         axis=2)[:, :, 0]     # [DP, Bl, d]
+            logits = logits_apply(cfg, params["embed"], h_last)
+            if use_sampler:
+                nxt = sample_tokens(logits, temps, topks, seeds, out_count)
+            else:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out_count = out_count + emit.astype(jnp.int32)
+            seq_full = state.seq_lens >= max_len - 1
+            done = active & ((out_count >= budget) | seq_full |
+                             (emit & (nxt == eos_id)))
+            last_tok = jnp.where(emit, nxt, last_tok)
+            n_emit = emit.astype(jnp.int32)
             tok_rows = jnp.concatenate(
-                [tok_rows, jnp.full((DP, Bl, T - Tv), -1, jnp.int32)],
-                axis=-1)
-    else:
-        # no drafts, no rollback
-        ctr_roll = [jnp.zeros_like(f) for f in free_in]
-        spill_roll = jnp.zeros_like(free_in[CLS_KV])
-        h_last = jnp.take_along_axis(hidden, idx[..., None, None],
-                                     axis=2)[:, :, 0]     # [DP, Bl, d]
-        logits = logits_apply(cfg, params["embed"], h_last)
-        if use_sampler:
-            nxt = sample_tokens(logits, temps, topks, seeds, out_count)
-        else:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out_count = out_count + emit.astype(jnp.int32)
-        seq_full = state.seq_lens >= max_len - 1
-        done = active & ((out_count >= budget) | seq_full |
-                         (emit & (nxt == eos_id)))
-        last_tok = jnp.where(emit, nxt, last_tok)
-        n_emit = emit.astype(jnp.int32)
-        tok_rows = jnp.concatenate(
-            [jnp.where(emit, nxt, -1)[..., None],
-             jnp.full((DP, Bl, T - 1), -1, jnp.int32)], axis=-1)
+                [jnp.where(emit, nxt, -1)[..., None],
+                 jnp.full((DP, Bl, T - 1), -1, jnp.int32)], axis=-1)
     state, spill_rel = _release_slots(state, done)
     # everything freed since the forward pass actually returned free —
     # spec rollback plus finished-slot release (shared/pinned pages a
@@ -699,10 +701,17 @@ class ServingEngine:
         eos = -1 if eos_id is None else int(eos_id)
         self.eos_id = eos_id
         self._spec_T = self.draft_len + 1
+        def serve_step(sampler, spec):
+            # named, so that its module reads ``jit_serve_step`` and its
+            # ops' ``tf_op`` ``jit(serve_step)/...`` in a profile
+            fn = functools.partial(_serve_step, cfg, self.capacity, eos,
+                                   sampler, spec, self._spec_T, self._axis)
+            fn.__name__ = "serve_step"
+            return fn
+
         self._serve_variants = {
             (sampler, spec): wrap(
-                functools.partial(_serve_step, cfg, self.capacity, eos,
-                                  sampler, spec, self._spec_T, self._axis),
+                serve_step(sampler, spec),
                 in_specs=(P(), S) + (R,) * 11,
                 out_specs=(S, R, R, P()),
                 donate=(1, 2, 3))
@@ -1579,15 +1588,42 @@ class ServingEngine:
         per-slot feed mutation so a fault there leaves host and device
         consistent; ``post_sync`` fires after the device round-trip but
         before bookkeeping/journaling — a crash there loses this step's
-        tokens and recovery must regenerate them."""
+        tokens and recovery must regenerate them.
+
+        Each part runs inside a host phase span (``Tracer.phase``,
+        DESIGN.md §13): ``serve.step`` around ``serve.admit``,
+        ``serve.feed``, ``serve.dispatch``, ``serve.sync``,
+        ``serve.post`` and ``serve.flight``."""
+        with self.tracer.phase("serve.step"):
+            return self._step()
+
+    def _step(self) -> bool:
+        phase = self.tracer.phase
         t0 = time.perf_counter()
-        self._fire("pre_tick")
-        self.scheduler.tick(self)
-        self._fire("post_admission")
+        with phase("serve.admit"):
+            self._fire("pre_tick")
+            self.scheduler.tick(self)
+            self._fire("post_admission")
         if not self.active:
             self.telemetry.inc("idle_steps")
             return False
+        with phase("serve.feed"):
+            T, drafts, feed, gen_slots = self._build_feed()
+        with phase("serve.dispatch"):
+            spec = any(gen_slots.values())
+            status = self._dispatch(T, spec, feed)
+        with phase("serve.sync"):
+            status = np.asarray(status)  # the step's ONE device->host sync
+        with phase("serve.post"):
+            now, ctr_block = self._post_sync(T, status, gen_slots)
+        dt = time.perf_counter() - t0
+        with phase("serve.flight"):
+            self._record_step(T, spec, dt, now, status, ctr_block, drafts)
+        return True
 
+    def _build_feed(self):
+        """This step's lane width, drafts, feed arrays and generating
+        slots (slot -> drafts fed)."""
         # schedule this step's lane widths (host-side bookkeeping only —
         # no device sync; prompt chunks come from host queues, decode
         # tokens from the device-resident last_tok register, draft
@@ -1661,20 +1697,28 @@ class ServingEngine:
                 # the KV the lane keeps (== tokens emitted) is only
                 # known after verification: _fed advances on status read
                 gen_slots[slot] = len(dr)
+        return T, drafts, (prompt_toks, feed_lens, is_prompt, emit), gen_slots
 
-        spec = any(gen_slots.values())
+    def _dispatch(self, T: int, spec: bool, feed) -> jax.Array:
+        """Dispatch the jitted serve step on ``feed``; returns its
+        packed status, still on the device."""
         sampler = bool(self._sampling_slots)
         self.step_variants.add((T, spec, sampler))
         serve = self._serve_variants[(sampler, spec)]
         self.state, self.last_tok, self.out_count, status = serve(
             self.params, self.state, self.last_tok, self.out_count,
             self.budget, self.temps, self.topks, self.seeds,
-            jnp.asarray(prompt_toks), jnp.asarray(feed_lens),
-            jnp.asarray(is_prompt), jnp.asarray(emit), self.expert_mask)
+            *(jnp.asarray(a) for a in feed), self.expert_mask)
         self.telemetry.inc("steps")
         self.telemetry.observe_hist("chunk_hist", T)
         self._fire("dispatched")
-        status = np.asarray(status)      # the step's ONE device->host sync
+        return status
+
+    def _post_sync(self, T: int, status: np.ndarray,
+                   gen_slots: Dict[int, int]) -> Tuple[float, np.ndarray]:
+        """Per-slot bookkeeping on the synced status: tokens, finishes,
+        prefix progress, pins.  Returns the engine clock's reading for
+        them and the device counter block."""
         self._fire("post_sync")
         n_emit = status[T + STATUS_EMITTED]
         done_row = status[T + STATUS_DONE]
@@ -1773,11 +1817,18 @@ class ServingEngine:
                     self._pinned_slots.add(slot)
                     self._maybe_pin(slot, list(req.prompt))
         self._fire("post_step")
+        return now, ctr_block
+
+    def _record_step(self, T: int, spec: bool, dt: float, now: float,
+                     status: np.ndarray, ctr_block: np.ndarray,
+                     drafts: Dict[int, List[int]]) -> None:
+        """Feed the step's wall time ``dt`` (entry to the end of
+        ``serve.post``) to the cost model, the watchdog and the flight
+        recorder."""
         # measured per-step cost model for the break-even gate: EWMA of
         # wall time keyed (lane width, spec).  The first dispatch at a
         # key pays jit compilation, so it is discarded — the second
         # sample seeds the EWMA.
-        dt = time.perf_counter() - t0
         ck = (T, spec)
         if ck in self._cost_seen:
             prev = self._step_cost.get(ck)
@@ -1785,8 +1836,7 @@ class ServingEngine:
                 0.8 * prev + 0.2 * dt)
         else:
             self._cost_seen.add(ck)
-        verdict = self.watchdog.observe(self.stats["steps"],
-                                        time.perf_counter() - t0)
+        verdict = self.watchdog.observe(self.stats["steps"], dt)
         if verdict == "straggler":
             self.telemetry.inc("stragglers")
         elif verdict == "timeout":
@@ -1809,7 +1859,6 @@ class ServingEngine:
                 "watchdog_timeout", {"step": self.stats["steps"]}):
             self.telemetry.inc("flight_dumps")
             self.tracer.instant("flight_dump", reason="watchdog_timeout")
-        return True
 
     def compiled_step_text(self, T: int, spec: bool = False,
                            sampler: bool = False) -> str:

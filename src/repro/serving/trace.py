@@ -12,14 +12,27 @@ Span model: ``pid`` is constant 0 (one engine process), ``tid`` is the
 request id, so Perfetto renders one row per request with its "request"
 (queued+active) and nested "active" (slot residency) spans; scheduler-
 and recovery-level events use the reserved ``tid`` = -1 engine row.
+
+Two more closed sets live here and go to the profiler, not the ring:
+host *phases* of the engine step (:meth:`Tracer.phase`, written as
+``jax.profiler.TraceAnnotation`` spans on the device trace's clock) and
+named *device scopes* (:func:`scope`, ``jax.named_scope`` on the
+compiled step's ops, read back as each op's ``tf_op``).
 """
 
 from __future__ import annotations
 
+import functools
+import gc
 import json
 import time
+import weakref
 from collections import deque
+from contextlib import nullcontext
 from typing import Optional
+
+import jax
+from jax.profiler import TraceAnnotation
 
 #: tid for engine-level (not per-request) events
 ENGINE_TID = -1
@@ -29,7 +42,6 @@ ENGINE_TID = -1
 SPAN_NAMES = frozenset({
     "request",        # B submit .. E finish/terminal-fail
     "active",         # B admit .. E preempt/finish/fail (slot residency)
-    "step",           # engine step span (engine row, sampled)
     "recover",        # recovery / reconcile window (engine row)
 })
 INSTANT_NAMES = frozenset({
@@ -39,6 +51,75 @@ INSTANT_NAMES = frozenset({
     "fail", "retry", "deadline_expired", "shed",
     "watchdog", "crash", "reconcile", "flight_dump", "shard_loss",
 })
+
+#: host phases of ``ServingEngine.step`` (DESIGN.md §13): ``serve.step``
+#: encloses, in order, admit / feed / dispatch / sync / post / flight
+#: (an idle step: admit only); ``serve.gc`` spans each Python garbage
+#: collection.  None may equal a span the chip benchmark writes itself
+#: (``window``, ``submit``, ``step``, ``record``, ``sleep``).
+PHASE_NAMES = frozenset({
+    "serve.step", "serve.admit", "serve.feed", "serve.dispatch",
+    "serve.sync", "serve.post", "serve.flight", "serve.gc",
+})
+#: named device scopes: ``alloc`` every classed-pool op and the step's
+#: page plan and table write, ``kv_write`` the KV scatter, ``kv_layout``
+#: the KV pool reshapes and transposes around the attention kernel,
+#: ``attn`` / ``ffn`` the sublayers, ``head`` projection, sampling and
+#: verification, ``layers`` the layer scan (its own per-layer slicing
+#: and write-back carry no inner scope)
+DEVICE_SCOPES = frozenset({
+    "alloc", "kv_write", "kv_layout", "attn", "ffn", "head", "layers",
+})
+
+_NO_SPAN = nullcontext()
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`DEVICE_SCOPES`."""
+    assert name in DEVICE_SCOPES, f"unknown device scope {name!r}"
+    return jax.named_scope(name)
+
+
+def scoped(name: str):
+    """Decorator: run the function under :func:`scope` ``(name)``."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return deco
+
+
+# ``serve.gc``: one gc callback for the process, installed while an
+# enabled Tracer lives (counted; each Tracer's finalizer uncounts)
+_gc_users = 0
+_gc_span = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_span
+    if phase == "start":
+        _gc_span = TraceAnnotation("serve.gc", generation=info["generation"])
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def _gc_hook() -> None:
+    global _gc_users
+    _gc_users += 1
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _gc_unhook() -> None:
+    global _gc_users
+    _gc_users -= 1
+    if _gc_users == 0 and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+        _on_gc("stop", {})        # a collection may be running now
 
 
 class Tracer:
@@ -53,6 +134,9 @@ class Tracer:
         self._clock = clock
         self._t0 = clock()
         self._open: dict = {}       # (name, tid) -> open-span depth
+        if enabled:
+            _gc_hook()
+            weakref.finalize(self, _gc_unhook)
 
     def _ts_us(self) -> float:
         return (self._clock() - self._t0) * 1e6
@@ -93,6 +177,17 @@ class Tracer:
         assert name in INSTANT_NAMES, f"unknown instant {name!r}"
         self._push({"name": name, "ph": "i", "ts": self._ts_us(),
                     "pid": 0, "tid": int(tid), "s": "t", "args": args})
+
+    def phase(self, name: str):
+        """Context manager: a host phase span (:data:`PHASE_NAMES`)
+        written to the profiler as a ``TraceAnnotation`` — on the device
+        trace's clock when a profile is recorded, one annotation object
+        when none is, nothing when the tracer is disabled.  Phases never
+        enter the ring."""
+        if not self.enabled:
+            return _NO_SPAN
+        assert name in PHASE_NAMES, f"unknown phase {name!r}"
+        return TraceAnnotation(name)
 
     # ----------------------------------------------------------- exports
     def to_chrome(self) -> dict:

@@ -11,8 +11,10 @@ export is schema-validated with strict span nesting, and the one-sync /
 one-collective discipline is re-asserted with telemetry fully enabled.
 """
 
+import gc
 import json
 import os
+import re
 
 import numpy as np
 import jax
@@ -27,7 +29,9 @@ from repro.serving.telemetry import (CTR_ALLOC, CTR_DRAIN, CTR_FREED,
                                      CTR_MARGIN, CTR_REFILL, CTR_ROLLBACK,
                                      CTR_SHARED_FREE, CTR_SPILL, N_CTR,
                                      FlightRecorder, Telemetry, parse_prom)
-from repro.serving.trace import Tracer, validate_chrome
+from repro.serving import trace as trace_mod
+from repro.serving.trace import (DEVICE_SCOPES, PHASE_NAMES, Tracer,
+                                 validate_chrome)
 
 
 @pytest.fixture(scope="module")
@@ -496,6 +500,127 @@ def test_trace_preemption_reopens_active_span(engine_setup):
                    if e["tid"] == tid and e["name"] == "active"]
         assert len(actives) >= 4, (
             "preempted request should close and reopen its active span")
+
+
+# =============================================== phases / device scopes
+
+
+STEP_PHASES = ["serve.admit", "serve.feed", "serve.dispatch", "serve.sync",
+               "serve.post", "serve.flight"]
+
+
+@pytest.fixture
+def phase_sink(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``: records
+    ``(depth, name)`` on entry, no profiler involved (garbage
+    collections, ``serve.gc``, are left out)."""
+    got, depth = [], [0]
+
+    class Sink:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs = name, kwargs
+
+        def __enter__(self):
+            if self.name != "serve.gc":
+                got.append((depth[0], self.name))
+                depth[0] += 1
+            return self
+
+        def __exit__(self, *exc):
+            if self.name != "serve.gc":
+                depth[0] -= 1
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Sink)
+    return got
+
+
+def test_dispatching_step_nests_its_phases_in_order(engine_setup,
+                                                    phase_sink):
+    cfg, params = engine_setup
+    eng = ServingEngine(cfg, params, dp=1, b_local=2, max_len=64,
+                        tracer=Tracer())
+    eng.submit(Request(0, prompt=list(range(1, 9)), max_new_tokens=4))
+    assert eng.step()
+    assert phase_sink == [(0, "serve.step")] + [(1, n) for n in STEP_PHASES]
+    # the ring keeps the request lifecycle only
+    assert not {e["name"] for e in eng.tracer.events} & PHASE_NAMES
+
+
+def test_idle_step_emits_step_and_admit_only(engine_setup, phase_sink):
+    cfg, params = engine_setup
+    eng = ServingEngine(cfg, params, dp=1, b_local=2, max_len=64,
+                        tracer=Tracer())
+    assert not eng.step()
+    assert phase_sink == [(0, "serve.step"), (1, "serve.admit")]
+
+
+def test_disabled_tracer_writes_no_phase(engine_setup, phase_sink):
+    cfg, params = engine_setup
+    eng = ServingEngine(cfg, params, dp=1, b_local=2, max_len=64)
+    eng.submit(Request(0, prompt=list(range(1, 9)), max_new_tokens=2))
+    assert eng.step()
+    assert phase_sink == []
+
+
+def test_gc_span_while_an_enabled_tracer_lives(monkeypatch):
+    spans = []
+
+    class Sink:
+        def __init__(self, name, **kwargs):
+            spans.append((name, kwargs))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Sink)
+    gc.collect()
+    users = trace_mod._gc_users
+    tracer = Tracer()
+    Tracer(enabled=False)
+    assert trace_mod._gc_users == users + 1
+    assert trace_mod._on_gc in gc.callbacks
+    gc.collect()
+    assert ("serve.gc", {"generation": 2}) in spans
+    del tracer
+    gc.collect()
+    assert trace_mod._gc_users == users
+    assert (trace_mod._on_gc in gc.callbacks) == bool(users)
+
+
+def test_phase_and_scope_names_are_closed():
+    with pytest.raises(AssertionError):
+        Tracer().phase("serve.nope")
+    with pytest.raises(AssertionError):
+        trace_mod.scope("nope")
+    assert all(n.startswith("serve.") for n in PHASE_NAMES)
+    assert "step" not in trace_mod.SPAN_NAMES
+
+
+def test_serve_step_hlo_names_its_scopes(engine_setup):
+    """The smoke serve step's HLO is module ``jit_serve_step`` and its
+    ops carry the device scopes in their ``op_name`` metadata."""
+    from jax._src.lib import xla_client
+    cfg, params = engine_setup
+    eng = ServingEngine(cfg, params, dp=1, b_local=2, max_len=64)
+    T = eng.chunk
+    feed = (jax.ShapeDtypeStruct((1, 2, T), jnp.int32),
+            jax.ShapeDtypeStruct((1, 2), jnp.int32),
+            jax.ShapeDtypeStruct((1, 2), bool),
+            jax.ShapeDtypeStruct((1, 2), bool))
+    module = eng._serve_variants[(False, False)].lower(
+        eng.params, eng.state, eng.last_tok, eng.out_count, eng.budget,
+        eng.temps, eng.topks, eng.seeds, *feed,
+        eng.expert_mask).compiler_ir("hlo").as_hlo_module()
+    assert module.name == "jit_serve_step"
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_metadata = True
+    parts = set()
+    for name in re.findall(r'op_name="([^"]*)"', module.to_string(opts)):
+        parts.update(name.split("/"))
+    assert DEVICE_SCOPES <= parts
 
 
 # ============================================ one sync / one collective
