@@ -7,10 +7,12 @@ boundary allocates a page from the :mod:`hier_pool`/:mod:`block_pool`
 (constant time, the paper's contribution).  Attention kernels read
 through the page table (see ``repro.kernels.paged_attention``).
 
-Layout choice for TPU: pages store K and V as
-``[num_pages, page_size, kv_heads, head_dim]`` so that a page is a
-(page_size x head_dim) VMEM tile per head — head_dim is kept a multiple
-of 128 by configs, aligning gathers with the MXU/VPU lanes.
+Layout choice for TPU: pages store K and V head-major,
+``[num_pages, kv_heads, page_size, head_dim]``, so that a page of one
+head is a contiguous (page_size x head_dim) VMEM tile, which the
+kernels read in place — head_dim is kept a multiple of 128 by configs,
+aligning the tiles with the MXU/VPU lanes.  A token's K or V is one
+``head_dim`` row per head.
 """
 
 from __future__ import annotations
@@ -26,8 +28,8 @@ from .block_pool import BlockPool, NULL
 
 class PagedKVCache(NamedTuple):
     pool: BlockPool           # page allocator
-    k_pages: jax.Array        # [num_pages, page_size, kv_heads, head_dim]
-    v_pages: jax.Array        # [num_pages, page_size, kv_heads, head_dim]
+    k_pages: jax.Array        # [num_pages, kv_heads, page_size, head_dim]
+    v_pages: jax.Array        # [num_pages, kv_heads, page_size, head_dim]
     page_tables: jax.Array    # int32[max_seqs, max_pages_per_seq]
     seq_lens: jax.Array       # int32[max_seqs] — tokens currently stored
 
@@ -37,15 +39,15 @@ def create(num_pages: int, page_size: int, kv_heads: int, head_dim: int,
            dtype=jnp.bfloat16) -> PagedKVCache:
     return PagedKVCache(
         pool=block_pool.create(num_pages),
-        k_pages=jnp.zeros((num_pages, page_size, kv_heads, head_dim), dtype),
-        v_pages=jnp.zeros((num_pages, page_size, kv_heads, head_dim), dtype),
+        k_pages=jnp.zeros((num_pages, kv_heads, page_size, head_dim), dtype),
+        v_pages=jnp.zeros((num_pages, kv_heads, page_size, head_dim), dtype),
         page_tables=jnp.full((max_seqs, max_pages_per_seq), NULL, jnp.int32),
         seq_lens=jnp.zeros((max_seqs,), jnp.int32),
     )
 
 
 def page_size(cache: PagedKVCache) -> int:
-    return cache.k_pages.shape[1]
+    return cache.k_pages.shape[2]
 
 
 def append(cache: PagedKVCache, k: jax.Array, v: jax.Array,
@@ -76,9 +78,10 @@ def append(cache: PagedKVCache, k: jax.Array, v: jax.Array,
     # previous read-modify-write idiom (write old value back to page 0)
     # raced with a real write to page 0 at the same position
     tgt = jnp.where(write, page_ids, cache.k_pages.shape[0])
-    k_pages = cache.k_pages.at[tgt, pos_in_page].set(
+    idx = (tgt[:, None], jnp.arange(k.shape[1]), pos_in_page[:, None])
+    k_pages = cache.k_pages.at[idx].set(
         k.astype(cache.k_pages.dtype), mode="drop")
-    v_pages = cache.v_pages.at[tgt, pos_in_page].set(
+    v_pages = cache.v_pages.at[idx].set(
         v.astype(cache.v_pages.dtype), mode="drop")
 
     seq_lens = cache.seq_lens + write.astype(jnp.int32)
@@ -129,10 +132,10 @@ def append_chunk(cache: PagedKVCache, k: jax.Array, v: jax.Array,
     pid = page_tables[rows, jnp.minimum(pos // psz, maxp - 1)]
     write = write & (pid >= 0)
     pid = jnp.where(write, pid, num_pages)         # out-of-range => dropped
-    pip = pos % psz
-    k_pages = cache.k_pages.at[pid, pip].set(
+    idx = (pid[..., None], jnp.arange(k.shape[2]), (pos % psz)[..., None])
+    k_pages = cache.k_pages.at[idx].set(
         k.astype(cache.k_pages.dtype), mode="drop")
-    v_pages = cache.v_pages.at[pid, pip].set(
+    v_pages = cache.v_pages.at[idx].set(
         v.astype(cache.v_pages.dtype), mode="drop")
 
     seq_lens = L + n
@@ -238,7 +241,8 @@ def share_prefix(cache: PagedKVCache, dst: int, src: int,
 
 def gather_kv(cache: PagedKVCache, seq_id: int | jax.Array,
               max_len: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Materialize a sequence's K/V up to max_len (reference path / tests).
+    """Materialize a sequence's K/V up to max_len (reference path / tests):
+    token-major k, v of [n_pages * psz, kv_heads, head_dim] and a valid mask.
 
     Production attention reads pages directly via the kernel; this is the
     jnp oracle used by ref implementations and the CPU dry-run path.
@@ -248,8 +252,10 @@ def gather_kv(cache: PagedKVCache, seq_id: int | jax.Array,
     table = jax.lax.dynamic_slice(  # live tokens; masking trims the tail
         cache.page_tables, (seq_id, 0), (1, n_pages))[0]
     safe = jnp.maximum(table, 0)
-    k = cache.k_pages[safe].reshape(n_pages * psz, *cache.k_pages.shape[2:])
-    v = cache.v_pages[safe].reshape(n_pages * psz, *cache.v_pages.shape[2:])
+    kh, hd = cache.k_pages.shape[1], cache.k_pages.shape[3]
+    # [n_pages, KH, psz, hd] -> token-major [n_pages * psz, KH, hd]
+    k = jnp.swapaxes(cache.k_pages[safe], 1, 2).reshape(n_pages * psz, kh, hd)
+    v = jnp.swapaxes(cache.v_pages[safe], 1, 2).reshape(n_pages * psz, kh, hd)
     pos = jnp.arange(n_pages * psz)
     valid = ((pos < cache.seq_lens[seq_id]) & (pos < max_len)
              & jnp.repeat(table >= 0, psz))

@@ -8,8 +8,11 @@ Design (vLLM PagedAttention re-tiled for TPU; see DESIGN.md §2b):
   and drives the K/V page BlockSpec index_maps: page i of sequence b is
   DMA'd from HBM page ``table[b, i]`` — the block-table indirection of
   the paper's allocator, performed by the memory system, not by gathers.
-* K/V page tiles are [psz, hd] with hd padded to 128 lanes by config;
-  all q-heads of one kv-head group (GQA) are processed together as a
+* The KV pool is stored head-major, [P, KH, psz, hd], so a page of one
+  kv head is a contiguous [psz, hd] tile (hd padded to 128 lanes by
+  config).  The wrappers take that pool as it is stored and only merge
+  its leading dims (a free reshape): nothing is copied per call; all
+  q-heads of one kv-head group (GQA) are processed together as a
   [G, hd] tile (G = H // KH), so the MXU sees [G, hd] x [hd, psz].
 * Out-of-range pages (table[b, i] < 0) are skipped by masking; dead DMA
   is avoided by clamping the index to 0 (a resident page) — the mask
@@ -24,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ...serving.trace import scope
 
 NEG_INF = -1e30
 
@@ -141,14 +142,14 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
     """Chunked-prefill paged attention.
 
     q: [B, T, H, hd] — T new tokens per sequence, causally masked within
-    the chunk; k/v_pages: [P, psz, KH, hd] (the chunk's K/V already
+    the chunk; k/v_pages: [P, KH, psz, hd] (the chunk's K/V already
     appended); table: [B, maxp]; base_lens: int32[B] sequence lengths
     BEFORE the chunk.  Same scalar-prefetched block-table indirection as
     the decode kernel; the q tile packs all chunk tokens of one GQA
     group as [T*G, hd] rows so the MXU sees [T*G, hd] x [hd, psz].
     """
     B, T, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = H // KH
     scale = 1.0 / (hd ** 0.5)
@@ -156,9 +157,8 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
     # [B, T, KH, G, hd] -> [B, KH, T*G, hd]: row r = t * G + g
     qg = q.reshape(B, T, KH, G, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KH, T * G, hd)
-    with scope("kv_layout"):     # the whole pool, every call
-        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    kp = k_pages.reshape(P * KH, psz, hd)      # tile p * KH + h
+    vp = v_pages.reshape(P * KH, psz, hd)
 
     grid = (B, KH, maxp)
 
@@ -196,18 +196,17 @@ def paged_attention_chunk(q, k_pages, v_pages, page_table, base_lens,
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                     interpret: bool = False):
-    """q: [B, H, hd]; k/v_pages: [P, psz, KH, hd]; table: [B, maxp]."""
+    """q: [B, H, hd]; k/v_pages: [P, KH, psz, hd]; table: [B, maxp]."""
     B, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = H // KH
     scale = 1.0 / (hd ** 0.5)
 
-    # layout: group q by kv head -> [B, KH, G, hd]; pages to [P*? ] tiles
+    # group q by kv head -> [B, KH, G, hd]; pages to [P*KH, psz, hd] tiles
     qg = q.reshape(B, KH, G, hd)
-    with scope("kv_layout"):     # the whole pool, every call
-        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    kp = k_pages.reshape(P * KH, psz, hd)
+    vp = v_pages.reshape(P * KH, psz, hd)
 
     grid = (B, KH, maxp)
 

@@ -18,12 +18,13 @@ def paged_attention_chunk_ref(q, k_pages, v_pages, page_table, base_lens):
     softmax is guarded), so callers can ragged-mask afterwards.
     """
     B, T, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     L = maxp * psz
     safe = jnp.maximum(page_table, 0)
-    k = k_pages[safe].reshape(B, L, KH, hd)
-    v = v_pages[safe].reshape(B, L, KH, hd)
+    # [B, maxp, KH, psz, hd] -> token-major [B, L, KH, hd]
+    k = jnp.swapaxes(k_pages[safe], 2, 3).reshape(B, L, KH, hd)
+    v = jnp.swapaxes(v_pages[safe], 2, 3).reshape(B, L, KH, hd)
     if KH != H:
         k = jnp.repeat(k, H // KH, axis=2)
         v = jnp.repeat(v, H // KH, axis=2)
@@ -41,17 +42,18 @@ def paged_attention_chunk_ref(q, k_pages, v_pages, page_table, base_lens):
 
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens):
-    """q: [B, H, hd]; pages: [P, psz, KH, hd]; table: [B, maxp]; lens: [B].
+    """q: [B, H, hd]; pages: [P, KH, psz, hd]; table: [B, maxp]; lens: [B].
 
     GQA: H q-heads read from KH kv-heads (H % KH == 0).
     """
     B, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     L = maxp * psz
     safe = jnp.maximum(page_table, 0)
-    k = k_pages[safe].reshape(B, L, KH, hd)
-    v = v_pages[safe].reshape(B, L, KH, hd)
+    # [B, maxp, KH, psz, hd] -> token-major [B, L, KH, hd]
+    k = jnp.swapaxes(k_pages[safe], 2, 3).reshape(B, L, KH, hd)
+    v = jnp.swapaxes(v_pages[safe], 2, 3).reshape(B, L, KH, hd)
     if KH != H:
         k = jnp.repeat(k, H // KH, axis=2)
         v = jnp.repeat(v, H // KH, axis=2)

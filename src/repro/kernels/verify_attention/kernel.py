@@ -40,8 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...serving.trace import scope
-
 NEG_INF = -1e30
 
 
@@ -136,13 +134,14 @@ def verify_attention(q, k_pages, v_pages, page_table, base_lens,
     """Page-grouped verify attention.
 
     q: [B, T, H, hd] — T = k+1 verify positions per lane; k/v_pages:
-    [P, psz, KH, hd] (drafts' K/V already appended); table: [B, maxp];
+    [P, KH, psz, hd], head-major as the pool is stored (drafts' K/V
+    already appended); table: [B, maxp];
     base_lens: int32[B] lengths before the verify window.  Bit-for-bit
     the same math as `verify_attention_ref` / `paged_attention_chunk`,
     only the page-visit order differs.
     """
     B, T, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = H // KH
     TG = T * G
@@ -155,9 +154,8 @@ def verify_attention(q, k_pages, v_pages, page_table, base_lens,
     # [B, T, KH, G, hd] -> [B, KH, T*G, hd]: row r = t * G + g
     qg = q.reshape(B, T, KH, G, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KH, TG, hd)
-    with scope("kv_layout"):     # the whole pool, every call
-        kp = k_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
-        vp = v_pages.transpose(0, 2, 1, 3).reshape(P * KH, psz, hd)
+    kp = k_pages.reshape(P * KH, psz, hd)      # tile p * KH + h
+    vp = v_pages.reshape(P * KH, psz, hd)
 
     grid = (KH, n_items)
 

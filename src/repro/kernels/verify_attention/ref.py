@@ -16,7 +16,7 @@ NEG_INF = -1e30
 
 
 def verify_attention_ref(q, k_pages, v_pages, page_table, base_lens):
-    """q: [B, T, H, hd]; k/v_pages: [P, psz, KH, hd]; table: [B, maxp];
+    """q: [B, T, H, hd]; k/v_pages: [P, KH, psz, hd]; table: [B, maxp];
     base_lens: int32[B] sequence lengths BEFORE the verify window.
 
     Query token t of lane b sits at absolute position base_lens[b] + t
@@ -26,12 +26,13 @@ def verify_attention_ref(q, k_pages, v_pages, page_table, base_lens):
     engine can ragged-mask afterwards.
     """
     B, T, H, hd = q.shape
-    P, psz, KH, _ = k_pages.shape
+    P, KH, psz, _ = k_pages.shape
     maxp = page_table.shape[1]
     L = maxp * psz
     safe = jnp.maximum(page_table, 0)
-    k = k_pages[safe].reshape(B, L, KH, hd)
-    v = v_pages[safe].reshape(B, L, KH, hd)
+    # [B, maxp, KH, psz, hd] -> token-major [B, L, KH, hd]
+    k = jnp.swapaxes(k_pages[safe], 2, 3).reshape(B, L, KH, hd)
+    v = jnp.swapaxes(v_pages[safe], 2, 3).reshape(B, L, KH, hd)
     if KH != H:
         k = jnp.repeat(k, H // KH, axis=2)
         v = jnp.repeat(v, H // KH, axis=2)

@@ -85,12 +85,13 @@ def decode_state_shardings(cfg: ModelConfig, state_defs: tfm.DecodeState,
     if kh_ax is None:
         hd_ax = _maybe(mesh, cfg.hd, "model")
 
-    def kv_spec(sds):
-        # [stack, DP, pages|Bl, (psz|W), KH, hd]
+    def kv_spec(sds, kh=-2):
+        # rings, enc_kv [stack, DP, Bl, W, KH, hd]; pages head-major
+        # [stack, DP, pages, KH, psz, hd] (kh=3)
         nd = len(sds.shape)
         parts = [None] * nd
         parts[1] = dpa
-        parts[-2] = kh_ax
+        parts[kh] = kh_ax
         parts[-1] = hd_ax
         return _ns(mesh, P(*parts))
 
@@ -104,7 +105,7 @@ def decode_state_shardings(cfg: ModelConfig, state_defs: tfm.DecodeState,
             parts[cand] = _maybe(mesh, sds.shape[cand], "model")
         return _ns(mesh, P(*parts))
 
-    kv_pages = jax.tree.map(kv_spec, state_defs.kv_pages)
+    kv_pages = jax.tree.map(lambda a: kv_spec(a, kh=3), state_defs.kv_pages)
     rings = jax.tree.map(kv_spec, state_defs.rings)
     rec = jax.tree.map(rec_spec, state_defs.rec)
     enc_kv = None

@@ -124,8 +124,9 @@ def load_prefill(cfg, state: DecodeState, caches: Dict[str, Any],
             z = np.zeros((n, dp, b_local, pad, KH, hd), kd.dtype)
             kd = np.concatenate([kd, z], axis=3)
             vd = np.concatenate([vd, z], axis=3)
-        kd = kd.reshape(n, dp, b_local, n_pages, psz, KH, hd)
-        vd = vd.reshape(n, dp, b_local, n_pages, psz, KH, hd)
+        # token-major cache -> head-major pages [.., n_pages, KH, psz, hd]
+        kd = kd.reshape(n, dp, b_local, n_pages, psz, KH, hd).swapaxes(4, 5)
+        vd = vd.reshape(n, dp, b_local, n_pages, psz, KH, hd).swapaxes(4, 5)
         for d in range(dp):
             for b in range(b_local):
                 pids = tables[d, b, :n_pages]

@@ -228,7 +228,10 @@ def forward_prefill(cfg, params, tokens, extra: Optional[Dict] = None):
 class DecodeState(NamedTuple):
     """All per-sequence serving state, [DP, B_local, ...] layouts.
 
-    kv_pages:    dict pos -> (k, v) [n_stack, DP, pages_local, psz, KH, hd]
+    kv_pages:    dict pos -> (k, v) [n_stack, DP, pages_local, KH, psz, hd]
+                 — head-major pages: the attention kernels read the
+                 stack in place as [(n_stack*DP*pages_local)*KH, psz,
+                 hd] tiles (DESIGN.md §3)
     rings:       dict pos -> (k, v) [n_stack, DP, Bl, W, KH, hd]
     rec:         dict pos -> pytree of recurrent states [n_stack, DP, Bl, ...]
     page_tables: int32 [DP, Bl, max_pages]   (shared by all paged layers)
@@ -433,7 +436,7 @@ def decode_state_defs(cfg, dp: int, b_local: int, max_len: int,
 
     def entry(pos, kind, stack):
         if kind == "paged":
-            shp = (stack, dp, pages_local, psz, KH, hd)
+            shp = (stack, dp, pages_local, KH, psz, hd)
             kv_pages[pos] = (jax.ShapeDtypeStruct(shp, dt),
                              jax.ShapeDtypeStruct(shp, dt))
         elif kind == "ring":
@@ -452,7 +455,7 @@ def decode_state_defs(cfg, dp: int, b_local: int, max_len: int,
         pos = f"rem{j}"
         k = base_kind(k)
         if k == "global":
-            shp = (1, dp, pages_local, psz, KH, hd)
+            shp = (1, dp, pages_local, KH, psz, hd)
             kv_pages[pos] = (jax.ShapeDtypeStruct(shp, dt),) * 2
         elif k == "local":
             W = min(cfg.window or max_len, max_len)
@@ -532,42 +535,76 @@ def _rec_state_defs(cfg, kind, stack, dp, b_local):
 # ======================================================= chunked decode path
 
 @scoped("kv_write")
-def _paged_write_chunk(k_pages, v_pages, k_new, v_new, page_ids, pos_in_page,
-                       write):
-    """k_pages: [DP, P, psz, KH, hd]; k_new: [DP, Bl, T, KH, hd];
-    page_ids/pos_in_page/write: [DP, Bl, T].  One scatter of Bl*T tokens
-    per shard; masked tokens are dropped (out-of-range page index)."""
-    P = k_pages.shape[1]
-    pid = jnp.where(write, page_ids, P)
+def _paged_write_chunk(k_pages, v_pages, layer, k_new, v_new, tables, base,
+                       tok_valid):
+    """Write a chunk's K/V into layer ``layer`` of the stacked pools.
 
-    def one(kp, vp, kn, vn, pid, pip):
-        kp = kp.at[pid, pip].set(kn.astype(kp.dtype), mode="drop")
-        vp = vp.at[pid, pip].set(vn.astype(vp.dtype), mode="drop")
-        return kp, vp
+    k_pages: [L, DP, P, KH, psz, hd]; k_new: [DP, Bl, T, KH, hd];
+    tables: [DP, Bl, maxp]; base: [DP, Bl], the chunk's first position;
+    tok_valid: [DP, Bl, T].  Whole pages are rewritten: the n <=
+    ceil((T-1)/psz) + 1 pages each lane's chunk touches are gathered,
+    the chunk's tokens merged into their slots, and the pages scattered
+    back.  Layer, shard and page are the only scatter indices and the
+    window is the whole [KH, psz, hd] page, contiguous in the head-major
+    layout, so XLA updates the pool in place by page DMAs.  A scatter of
+    one ``hd`` row per token and head is in place too, but the TPU runs
+    its rows one by one (43 ms a 64-token step at olmo-1b widths); a
+    slice among the indices (``at[l, d, pid, :, pip]``) makes XLA pick
+    a psz-minor layout and copy the whole stack per layer.  A page with
+    no valid token is dropped (out-of-range page index); pages a lane
+    writes are its own (shared prefix pages are full, DESIGN.md §7)."""
+    DP, Bl, T = k_new.shape[:3]
+    P, _, psz = k_pages.shape[2:5]
+    maxp = tables.shape[2]
+    n = (T + psz - 2) // psz + 1
+    slot = base[..., None] // psz + jnp.arange(n)                # [DP,Bl,n]
+    pid = jnp.take_along_axis(tables, jnp.minimum(slot, maxp - 1), axis=2)
+    # slot s of page k holds chunk token t = k*psz + s - base % psz;
+    # t: [DP, Bl, n, psz]
+    t = (jnp.arange(n)[:, None] * psz + jnp.arange(psz)
+         - (base % psz)[..., None, None])
+    tc = jnp.clip(t, 0, T - 1).reshape(DP, Bl, n * psz)
+    valid = ((t >= 0) & (t < T) & jnp.take_along_axis(
+        tok_valid, tc, axis=2).reshape(t.shape))
+    valid = valid & ((pid >= 0) & (slot < maxp))[..., None]
+    tgt = jnp.where(jnp.any(valid, axis=3), pid, P)              # P => drop
+    d = jnp.arange(DP)[:, None, None]
+    keep = valid[:, :, :, None, :, None]
 
-    return jax.vmap(one)(k_pages, v_pages, k_new, v_new, pid, pos_in_page)
+    def write(pages, new):
+        rows = jnp.take_along_axis(new, tc[..., None, None], axis=2)
+        rows = rows.reshape(DP, Bl, n, psz, *new.shape[3:]).swapaxes(3, 4)
+        old = pages[layer, d, jnp.minimum(tgt, P - 1)]  # [DP,Bl,n,KH,psz,hd]
+        merged = jnp.where(keep, rows.astype(pages.dtype), old)
+        return pages.at[layer, d, tgt].set(merged, mode="drop")
+
+    return write(k_pages, k_new), write(v_pages, v_new)
 
 
-def _paged_attn_chunk(q, k_pages, v_pages, tables, base, verify=False):
-    """q: [DP, Bl, T, H, hd]; pages: [DP, P, psz, KH, hd]; base: [DP, Bl].
+def _paged_attn_chunk(q, k_pages, v_pages, layer, tables, base,
+                      verify=False):
+    """q: [DP, Bl, T, H, hd]; pages: the stacked [L, DP, P, KH, psz, hd];
+    layer: the layer read; base: [DP, Bl].
 
-    Folds DP into the kernel batch (shard-local page ids offset by d*P)
-    so one pallas_call / ref call covers all shards — no vmap over the
-    kernel.  Dispatches the Pallas chunk kernel on TPU, jnp ref elsewhere.
+    Reads the stack in place: merging its leading dims is a free
+    reshape, and shard d's page ids of layer ``layer`` are offset by
+    (layer*DP + d)*P, so one pallas_call / ref call covers all shards —
+    no vmap over the kernel, no slice of the layer.  Dispatches the
+    Pallas chunk kernel on TPU, jnp ref elsewhere.
     verify=True routes through the page-grouped verify-attention
     schedule (kernels/verify_attention) — bit-identical math, but each
     hot shared page is streamed from HBM once for all draft lanes
     reading it instead of once per lane.
     """
     DP, Bl, T, H, hd = q.shape
-    P = k_pages.shape[1]
+    L, _, P = k_pages.shape[:3]
     maxp = tables.shape[2]
     with scope("kv_layout"):
-        off = (jnp.arange(DP, dtype=jnp.int32) * P)[:, None, None]
-        tg = jnp.where(tables >= 0, tables + off, -1)
+        off = ((layer * DP + jnp.arange(DP, dtype=jnp.int32)) * P)
+        tg = jnp.where(tables >= 0, tables + off[:, None, None], -1)
         tg = tg.reshape(DP * Bl, maxp)
-        kg = k_pages.reshape((DP * P,) + k_pages.shape[2:])
-        vg = v_pages.reshape((DP * P,) + v_pages.shape[2:])
+        kg = k_pages.reshape((L * DP * P,) + k_pages.shape[3:])
+        vg = v_pages.reshape((L * DP * P,) + v_pages.shape[3:])
     op = verify_attention if verify else paged_attention_chunk
     o = op(q.reshape(DP * Bl, T, H, hd), kg, vg, tg, base.reshape(DP * Bl))
     return o.reshape(DP, Bl, T, H, hd)
@@ -658,13 +695,16 @@ def _xattn_decode_chunk(cfg, lp, x, enc_kv_layer):
 
 def _mix_decode_chunk(cfg, lp, x, kind, st_kind, layer_state, state,
                       positions, tok_valid, base, lens, enc_kv_layer=None,
-                      verify=False, expert_buf=None, expert_mask=None):
+                      verify=False, expert_buf=None, expert_mask=None,
+                      layer=0):
     """One layer over a chunk of up to T tokens per sequence.
 
-    x: [DP, Bl, T, d].  Attention layers process the chunk in parallel
-    (pages / ring written once, one chunk-attention call); recurrent
-    layers scan the chunk sequentially with per-token state gating so
-    ragged tails stay inert.  Returns (x, new_layer_state, moe_meta):
+    x: [DP, Bl, T, d].  A paged layer's ``layer_state`` is the whole
+    stacked (k, v) pool and ``layer`` its index there; every other
+    layer's is its own slice.  Attention layers process the chunk in
+    parallel (pages / ring written once, one chunk-attention call);
+    recurrent layers scan the chunk sequentially with per-token state
+    gating so ragged tails stay inert.  Returns (x, new_layer_state, moe_meta):
     ``moe_meta`` is None for non-MoE layers, else ``(dropped [DP],
     routed [DP, E])`` — capacity-dropped valid assignments per shard and
     valid kept assignments per expert (the §15 meters).
@@ -692,17 +732,11 @@ def _mix_decode_chunk(cfg, lp, x, kind, st_kind, layer_state, state,
             kd = k.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
             vd = v.reshape(DP, Bl, T, cfg.n_kv_heads, cfg.hd)
             if st_kind == "paged":
-                kp, vp = layer_state
-                psz = cfg.page_size
-                maxp = state.page_tables.shape[2]
-                pid = jnp.take_along_axis(
-                    state.page_tables, jnp.minimum(positions // psz, maxp - 1),
-                    axis=2)
-                write = tok_valid & (pid >= 0)
-                kp, vp = _paged_write_chunk(kp, vp, kd, vd, pid,
-                                            positions % psz, write)
-                o = _paged_attn_chunk(qd, kp, vp, state.page_tables, base,
-                                      verify=verify)
+                kp, vp = _paged_write_chunk(*layer_state, layer, kd, vd,
+                                            state.page_tables, base,
+                                            tok_valid)
+                o = _paged_attn_chunk(qd, kp, vp, layer, state.page_tables,
+                                      base, verify=verify)
                 new_state = (kp, vp)
             else:
                 kr, vr = layer_state
@@ -898,18 +932,26 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
     ebuf0 = {pos: _gather_expert_pages(state.expert_pages, tab[0])
              for pos, tab in etab_pat.items()}
 
+    # the stacked paged pools ride the scan's carry, not its xs/ys: each
+    # layer writes and reads its pages in place, with no per-layer slice,
+    # write-back or copy of the stack (DESIGN.md §3)
     def group_body(carry, xs):
-        x, ebuf, meters = carry
-        gparams, gstate, enc_kv_g, etab_g, etab_n = xs
-        new_gstate = {}
+        x, ebuf, meters, kv = carry
+        g, gparams, gstate, enc_kv_g, etab_g, etab_n = xs
+        kv, new_gstate = dict(kv), {}
         for j, kind in enumerate(cfg.pattern):
             pos = f"pos{j}"
+            paged = pos in kv
             x, ns, meta = _mix_decode_chunk(
-                cfg, gparams[pos], x, kind, st_kinds[pos], gstate[pos],
+                cfg, gparams[pos], x, kind, st_kinds[pos],
+                kv[pos] if paged else gstate[pos],
                 state, positions, tok_valid, base, lens,
                 enc_kv_g if has_x else None, verify=verify,
-                expert_buf=ebuf.get(pos), expert_mask=expert_mask)
-            new_gstate[pos] = ns
+                expert_buf=ebuf.get(pos), expert_mask=expert_mask, layer=g)
+            if paged:
+                kv[pos] = ns
+            else:
+                new_gstate[pos] = ns
             meters = absorb(meters, meta, etab_g.get(pos))
         # prefetch the NEXT group's expert pages: independent of this
         # group's FFN, so the gather DMA overlaps the compute above
@@ -920,13 +962,12 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
             meters["expert_prefetch_pages"] = (
                 meters["expert_prefetch_pages"] + jnp.sum(
                     tab_n >= 0, axis=(1, 2), dtype=jnp.int32))
-        return (x, new_ebuf, meters), new_gstate
+        return (x, new_ebuf, meters, kv), new_gstate
 
+    kv_g = {pos: kv for pos, kv in state.kv_pages.items()
+            if pos.startswith("pos")}
     if cfg.n_groups:
         gstates = {}
-        for pos, kv in state.kv_pages.items():
-            if pos.startswith("pos"):
-                gstates[pos] = kv
         for pos, kv in state.rings.items():
             if pos.startswith("pos"):
                 gstates[pos] = kv
@@ -940,11 +981,12 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
         else:
             enc_scan = (jnp.zeros((cfg.n_groups,)),) * 2  # placeholder
         # ``layers``: the scan's own per-layer slicing of the stacked
-        # weights and states and their write-back carry no inner scope
+        # weights and xs states and their write-back carry no inner scope
         with scope("layers"):
-            (x, _, meters), new_gstates = jax.lax.scan(
-                group_body, (x, ebuf0, meters),
-                (params["groups"], gstates, enc_scan, etab_pat, etab_next))
+            (x, _, meters, kv_g), new_gstates = jax.lax.scan(
+                group_body, (x, ebuf0, meters, kv_g),
+                (jnp.arange(cfg.n_groups, dtype=jnp.int32),
+                 params["groups"], gstates, enc_scan, etab_pat, etab_next))
     else:
         new_gstates = {}
 
@@ -954,10 +996,13 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
         bk = base_kind(kind)
         st_kind = ("paged" if bk == "global"
                    else "ring" if bk == "local" else "rec")
-        ls = (state.kv_pages.get(pos) or state.rings.get(pos)
-              or state.rec.get(pos))
-        with scope("layers"):
-            ls0 = jax.tree.map(lambda a: a[0], ls)
+        paged = pos in state.kv_pages
+        if paged:       # a stack of 1, read and written in place at 0
+            ls0 = state.kv_pages[pos]
+        else:
+            ls = state.rings.get(pos) or state.rec.get(pos)
+            with scope("layers"):
+                ls0 = jax.tree.map(lambda a: a[0], ls)
         lp = params["rem"][f"pos{j}"]
         enc_l = None
         if has_x and state.enc_kv is not None:
@@ -972,13 +1017,16 @@ def forward_decode_chunk(cfg, params, tokens, state: DecodeState, lens,
                                         lens, enc_l, verify=verify,
                                         expert_buf=ebuf_r,
                                         expert_mask=expert_mask)
-        with scope("layers"):
-            new_rem_states[pos] = jax.tree.map(lambda a: a[None], ns)
+        if paged:
+            new_rem_states[pos] = ns
+        else:
+            with scope("layers"):
+                new_rem_states[pos] = jax.tree.map(lambda a: a[None], ns)
         meters = absorb(meters, meta, tab_r)
 
     kv_pages, rings, rec = {}, {}, {}
     for pos in state.kv_pages:
-        src = new_gstates if pos.startswith("pos") else new_rem_states
+        src = kv_g if pos.startswith("pos") else new_rem_states
         kv_pages[pos] = src[pos]
     for pos in state.rings:
         src = new_gstates if pos.startswith("pos") else new_rem_states

@@ -561,7 +561,7 @@ def _share_from_row(psz: int, state, dst_oh, src_row, n_tokens,
     tgt = jnp.where(shard_mask & ok & (partial > 0) & (fresh_shard >= 0),
                     fresh_shard, P)                                # P => drop
 
-    def copy_pages(pages):                        # [S, DP, P, psz, KH, hd]
+    def copy_pages(pages):                        # [S, DP, P, KH, psz, hd]
         def per_shard(pg, t):
             return pg.at[:, t].set(pg[:, src_pid], mode="drop")
         return jax.vmap(per_shard, in_axes=(1, 0), out_axes=1)(pages, tgt)

@@ -63,7 +63,7 @@ PHASE_NAMES = frozenset({
 })
 #: named device scopes: ``alloc`` every classed-pool op and the step's
 #: page plan and table write, ``kv_write`` the KV scatter, ``kv_layout``
-#: the KV pool reshapes and transposes around the attention kernel,
+#: the page-table offset into the stacked KV pool the kernel reads,
 #: ``attn`` / ``ffn`` the sublayers, ``head`` projection, sampling and
 #: verification, ``layers`` the layer scan (its own per-layer slicing
 #: and write-back carry no inner scope)

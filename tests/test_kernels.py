@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracle."""
 
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -31,8 +33,8 @@ class TestPagedAttention:
     def test_vs_ref(self, B, H, KH, hd, psz, maxp, P, dt):
         rng = np.random.RandomState(hash((B, H, KH)) % 2**31)
         q = jnp.asarray(rng.randn(B, H, hd), dt)
-        kp = jnp.asarray(rng.randn(P, psz, KH, hd), dt)
-        vp = jnp.asarray(rng.randn(P, psz, KH, hd), dt)
+        kp = jnp.asarray(rng.randn(P, KH, psz, hd), dt)
+        vp = jnp.asarray(rng.randn(P, KH, psz, hd), dt)
         lens = jnp.asarray(rng.randint(1, maxp * psz, B), jnp.int32)
         table = np.full((B, maxp), -1, np.int32)
         used = set()
@@ -57,8 +59,8 @@ class TestPagedAttention:
         rng = np.random.RandomState(seed)
         B, H, KH, hd, P = 2, 4, 2, 64, 24
         q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
-        kp = jnp.asarray(rng.randn(P, psz, KH, hd), jnp.float32)
-        vp = jnp.asarray(rng.randn(P, psz, KH, hd), jnp.float32)
+        kp = jnp.asarray(rng.randn(P, KH, psz, hd), jnp.float32)
+        vp = jnp.asarray(rng.randn(P, KH, psz, hd), jnp.float32)
         lens = jnp.asarray(rng.randint(1, maxp * psz, B), jnp.int32)
         table = np.full((B, maxp), -1, np.int32)
         avail = list(range(P))
@@ -80,8 +82,8 @@ class TestPagedAttentionChunk:
         base = rng.randint(0, hi, B).astype(np.int32) if ragged_base \
             else np.zeros(B, np.int32)
         q = jnp.asarray(rng.randn(B, T, H, hd), dt)
-        kp = jnp.asarray(rng.randn(P, psz, KH, hd), dt)
-        vp = jnp.asarray(rng.randn(P, psz, KH, hd), dt)
+        kp = jnp.asarray(rng.randn(P, KH, psz, hd), dt)
+        vp = jnp.asarray(rng.randn(P, KH, psz, hd), dt)
         table = np.full((B, maxp), -1, np.int32)
         avail = list(range(P))
         rng.shuffle(avail)
@@ -134,8 +136,8 @@ class TestPagedAttentionChunk:
         for t in range(tcut, T):
             pos = int(base[0]) + t
             pid = tbl[0, pos // psz]
-            kp2[pid, pos % psz] = 99.0
-            vp2[pid, pos % psz] = -99.0
+            kp2[pid, :, pos % psz] = 99.0
+            vp2[pid, :, pos % psz] = -99.0
         out2 = paged_attention_chunk_ref(q, jnp.asarray(kp2),
                                          jnp.asarray(vp2), table, base)
         np.testing.assert_allclose(np.asarray(out1[:, :tcut]),
@@ -173,6 +175,87 @@ class TestPagedAttentionChunk:
         out = paged_attention_chunk(q, kp, vp, table, base, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-4, rtol=1e-4)
+
+
+class TestStackedPool:
+    """The serve step reads the stacked pool [L, DP, P, KH, psz, hd] in
+    place: ``_paged_attn_chunk`` offsets shard d's page ids of layer l
+    by (l*DP + d)*P into the merged stack.  Every layer and shard holds
+    different data, so a wrong offset reads another layer's pages."""
+
+    @pytest.mark.parametrize("kernel,T", [("chunk", 1), ("chunk", 64),
+                                          ("verify", 5)])
+    def test_layer_offset_reads_that_layers_pages(self, monkeypatch,
+                                                  kernel, T):
+        from repro.kernels.paged_attention import ops as paged_ops
+        from repro.kernels.verify_attention import ops as verify_ops
+        from repro.kernels.verify_attention.ref import verify_attention_ref
+        from repro.models import transformer as tfm
+        monkeypatch.setattr(tfm, "paged_attention_chunk", functools.partial(
+            paged_ops.paged_attention_chunk, force="interpret"))
+        monkeypatch.setattr(tfm, "verify_attention", functools.partial(
+            verify_ops.verify_attention, force="interpret"))
+        rng = np.random.RandomState(17 + T)
+        L, DP, Bl, H, KH, hd, psz, maxp, P = 3, 2, 2, 4, 2, 64, 16, 6, 16
+        layer = 2
+        kst = jnp.asarray(rng.randn(L, DP, P, KH, psz, hd), jnp.float32)
+        vst = jnp.asarray(rng.randn(L, DP, P, KH, psz, hd), jnp.float32)
+        q = jnp.asarray(rng.randn(DP, Bl, T, H, hd), jnp.float32)
+        base = rng.randint(0, maxp * psz - T, (DP, Bl)).astype(np.int32)
+        tables = np.full((DP, Bl, maxp), -1, np.int32)
+        for d in range(DP):
+            avail = list(rng.permutation(P))
+            for b in range(Bl):
+                for i in range(-(-(base[d, b] + T) // psz)):
+                    tables[d, b, i] = avail.pop()
+        tables, base = jnp.asarray(tables), jnp.asarray(base)
+        out = tfm._paged_attn_chunk(q, kst, vst, jnp.int32(layer), tables,
+                                    base, verify=kernel == "verify")
+        ref_fn = (verify_attention_ref if kernel == "verify"
+                  else paged_attention_chunk_ref)
+        for d in range(DP):
+            ref = ref_fn(q[d], kst[layer, d], vst[layer, d], tables[d],
+                         base[d])
+            np.testing.assert_allclose(np.asarray(out[d]), np.asarray(ref),
+                                       atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("T", [1, 5, 8, 13])
+    def test_chunk_write_touches_only_its_slots(self, T):
+        """``_paged_write_chunk`` rewrites whole pages: every valid token
+        of layer l lands in its page's slot, every other slot of every
+        layer and shard keeps its value, and tokens past a lane's length
+        or on an unmapped page are dropped."""
+        from repro.models import transformer as tfm
+        rng = np.random.RandomState(29 + T)
+        L, DP, Bl, KH, hd, psz, maxp, P = 3, 2, 3, 2, 16, 8, 5, 16
+        layer = 1
+        kst = rng.randn(L, DP, P, KH, psz, hd).astype(np.float32)
+        vst = rng.randn(L, DP, P, KH, psz, hd).astype(np.float32)
+        kn = rng.randn(DP, Bl, T, KH, hd).astype(np.float32)
+        vn = rng.randn(DP, Bl, T, KH, hd).astype(np.float32)
+        base = rng.randint(0, maxp * psz - T + 1, (DP, Bl)).astype(np.int32)
+        lens = rng.randint(1, T + 1, (DP, Bl))
+        lens[0, 0] = 0                                   # an idle lane
+        tables = np.full((DP, Bl, maxp), -1, np.int32)
+        for d in range(DP):
+            tables[d] = rng.permutation(P)[:Bl * maxp].reshape(Bl, maxp)
+        tables[1, 2, (base[1, 2] + T - 1) // psz] = -1  # an unmapped page
+        valid = np.arange(T)[None, None] < lens[..., None]
+        k2, v2 = tfm._paged_write_chunk(
+            jnp.asarray(kst), jnp.asarray(vst), jnp.int32(layer),
+            jnp.asarray(kn), jnp.asarray(vn), jnp.asarray(tables),
+            jnp.asarray(base), jnp.asarray(valid))
+        want_k, want_v = kst.copy(), vst.copy()
+        for d in range(DP):
+            for b in range(Bl):
+                for t in range(lens[d, b]):
+                    pos = base[d, b] + t
+                    pid = tables[d, b, pos // psz]
+                    if pid >= 0:
+                        want_k[layer, d, pid, :, pos % psz] = kn[d, b, t]
+                        want_v[layer, d, pid, :, pos % psz] = vn[d, b, t]
+        np.testing.assert_array_equal(np.asarray(k2), want_k)
+        np.testing.assert_array_equal(np.asarray(v2), want_v)
 
 
 class TestFlashAttention:

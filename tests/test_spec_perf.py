@@ -60,8 +60,8 @@ class TestVerifyAttentionKernel:
     def test_vs_ref_sweep(self, B, T, H, KH, hd, psz, maxp, P, overlap):
         rng = np.random.RandomState(hash((B, T, psz, overlap)) % 2 ** 31)
         q = jnp.asarray(rng.randn(B, T, H, hd), jnp.float32)
-        kp = jnp.asarray(rng.randn(P, psz, KH, hd), jnp.float32)
-        vp = jnp.asarray(rng.randn(P, psz, KH, hd), jnp.float32)
+        kp = jnp.asarray(rng.randn(P, KH, psz, hd), jnp.float32)
+        vp = jnp.asarray(rng.randn(P, KH, psz, hd), jnp.float32)
         tbl, base = _spec_tables(rng, B, T, psz, maxp, P, overlap)
         ref = verify_attention_ref(q, kp, vp, tbl, base)
         out = verify_attention_kernel(q, kp, vp, tbl, base, interpret=True)
@@ -76,8 +76,8 @@ class TestVerifyAttentionKernel:
             paged_attention_chunk_ref)
         rng = np.random.RandomState(11)
         q = jnp.asarray(rng.randn(3, 4, 4, 16), jnp.float32)
-        kp = jnp.asarray(rng.randn(24, 8, 2, 16), jnp.float32)
-        vp = jnp.asarray(rng.randn(24, 8, 2, 16), jnp.float32)
+        kp = jnp.asarray(rng.randn(24, 2, 8, 16), jnp.float32)
+        vp = jnp.asarray(rng.randn(24, 2, 8, 16), jnp.float32)
         tbl, base = _spec_tables(rng, 3, 4, 8, 5, 24, "prefix")
         a = verify_attention_ref(q, kp, vp, tbl, base)
         b = paged_attention_chunk_ref(q, kp, vp, tbl, base)
